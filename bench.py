@@ -1,9 +1,7 @@
 """Benchmark driver — prints ONE JSON line with the headline metric.
 
-Headline (BASELINE.md): echo RPC throughput. Until the native echo path
-lands this reports the flagship-model forward throughput on the real chip;
-once brpc_tpu.rpc + native core are in, this runs the echo benchmark
-(multi_threaded_echo analog) and reports QPS vs the reference's 500k QPS
+Headline (BASELINE.md): echo RPC throughput through the native core (the
+multi_threaded_echo analog), reported as QPS vs the reference's 500k QPS
 production claim (docs/en/overview.md:88).
 
 JSON schema (one line on stdout):
@@ -31,54 +29,17 @@ bench dies at teardown (BENCH_r05 rc 139).
 """
 import argparse
 import json
-import sys
-import time
 
 
 def bench_echo():
-    """Echo QPS over loopback using the framework's RPC stack. Headline is
-    the native C++ data path (multi_threaded_echo analog); falls back to
-    the pure-Python stack when the native toolchain is absent."""
-    try:
-        from brpc_tpu import native
+    """Echo QPS over loopback through the native C++ data path (the
+    multi_threaded_echo analog). The native core must build: a failed
+    build raises with the compiler's error."""
+    from brpc_tpu import native
+    from brpc_tpu.bench import framework_echo_bench
 
-        if native.available():
-            from brpc_tpu.bench import framework_echo_bench
-
-            return framework_echo_bench()
-    except Exception:
-        pass
-    from brpc_tpu.bench import echo_bench  # implemented with the rpc layer
-
-    return echo_bench()
-
-
-def bench_model_fwd():
-    import jax
-    import jax.numpy as jnp
-
-    from brpc_tpu.tensor import ModelConfig, forward_local, init_params
-
-    cfg = ModelConfig(vocab=256, d_model=256, n_heads=8, d_head=32,
-                      d_ff=512, n_layers=4, n_experts=4)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    B, T = 8, 512
-    tokens = jnp.zeros((B, T), dtype=jnp.int32)
-    fn = jax.jit(lambda p, t: forward_local(p, t, cfg))
-    fn(params, tokens)[0].block_until_ready()  # compile
-    n_iters = 20
-    t0 = time.perf_counter()
-    for _ in range(n_iters):
-        out = fn(params, tokens)
-    out.block_until_ready()
-    dt = time.perf_counter() - t0
-    tok_s = B * T * n_iters / dt
-    return {
-        "metric": "flagship_fwd_tokens_per_s",
-        "value": round(tok_s, 1),
-        "unit": "tokens/s",
-        "vs_baseline": 0.0,
-    }
+    native.load()
+    return framework_echo_bench()
 
 
 def main():
@@ -96,38 +57,26 @@ def main():
         import os
 
         os.environ["BRPC_TPU_CONN_SCALE"] = str(args.conn_scale)
-    try:
-        result = bench_echo()
-    except (ImportError, ModuleNotFoundError):
-        # Echo bench not built yet — report the model-forward metric. Real
-        # failures inside an existing echo bench must propagate, not be
-        # silently replaced by a different headline metric.
-        result = bench_model_fwd()
+    from brpc_tpu import compile_cache
+
+    compile_cache.enable()
+    result = bench_echo()
     # device-side figure riding the extras (the rdma_performance north
     # star): achieved allreduce bandwidth — only meaningful on a REAL
     # multi-device mesh (one device moves zero inter-chip bytes)
-    try:
-        import jax
+    import jax
 
-        if len(jax.devices()) > 1:
-            from brpc_tpu.bench import collective_bench
+    if len(jax.devices()) > 1:
+        from brpc_tpu.bench import collective_bench
 
-            coll = collective_bench(nbytes=1 << 24, iters=10)
-            result.setdefault("extra", {})["allreduce_GBps"] = coll["value"]
-    except Exception:
-        pass
+        coll = collective_bench(nbytes=1 << 24, iters=10)
+        result.setdefault("extra", {})["allreduce_GBps"] = coll["value"]
     # multicore scaling curve (--cpus N): qps at {1..N} cpus, pinned
     # server/client processes — sublinear scaling is a bench-gate finding
     if args.cpus > 0:
-        try:
-            from brpc_tpu import native
-            from brpc_tpu.bench import scaling_bench
+        from brpc_tpu.bench import scaling_bench
 
-            if native.available():
-                result.setdefault("extra", {})["scaling"] = \
-                    scaling_bench(args.cpus)
-        except Exception:
-            pass
+        result.setdefault("extra", {})["scaling"] = scaling_bench(args.cpus)
     print(json.dumps(result))
 
 

@@ -145,7 +145,7 @@ def contention_profile(seconds: float = 1.0, hz: int = 99) -> str:
 
 def tpu_trace(seconds: float = 1.0):
     """XProf/libtpu trace via jax.profiler; returns (content_type, body).
-    Loading the zip into TensorBoard's profile plugin gives the device
+    Loading the zip into TensorBoard's profile viewer gives the device
     timeline — the TPU-idiomatic /hotspots backend (SURVEY §5)."""
     seconds = max(0.1, min(30.0, seconds))
     import tempfile

@@ -359,14 +359,13 @@ class SwarmPool:
                 self._next_port += ports_per
             churn = self._env.get("BRPC_TPU_CHURN_FAULT") or \
                 os.environ.get("BRPC_TPU_CHURN_FAULT")
-            env = dict(self._env)
+            # members are host-only: a chip belongs to one process
+            env = dict(self._env, JAX_PLATFORMS="cpu")
             if churn:
                 env["NAT_FAULT"] = churn
             script = (
                 "import os, signal, sys\n"
                 "sys.path.insert(0, '.')\n"
-                "import jax\n"
-                "jax.config.update('jax_platforms', 'cpu')\n"
                 "from brpc_tpu import native\n"
                 f"base, count = {base}, {ports_per}\n"
                 "try:\n"

@@ -191,23 +191,25 @@ class NatClusterRow(ctypes.Structure):
     ]
 
 
-def _build() -> bool:
-    try:
-        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True, timeout=300)
-        return True
-    except subprocess.CalledProcessError as e:
-        import sys
+def _build() -> None:
+    """Bring the library up to date with `make`. Serialised across
+    processes by a file lock: the test runner's workers all load the
+    library while collecting, and each must see the same outcome.
+    Raises NativeUnavailable carrying the compiler's error."""
+    import fcntl
 
-        # a failed rebuild with a stale .so present would otherwise die
-        # later with a confusing missing-symbol AttributeError
-        sys.stderr.write(
-            "brpc_tpu.native: rebuild FAILED — a cached library may be "
-            "stale:\n" + (e.stderr or b"").decode(errors="replace")[-2000:]
-            + "\n")
-        return False
-    except (subprocess.TimeoutExpired, FileNotFoundError):
-        return False
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                           capture_output=True, timeout=600)
+        except subprocess.CalledProcessError as e:
+            raise NativeUnavailable(
+                "native build failed:\n" +
+                (e.stderr or b"").decode(errors="replace")[-4000:]) from None
+        except (subprocess.TimeoutExpired, FileNotFoundError) as e:
+            raise NativeUnavailable(
+                f"native build could not run: {e}") from None
 
 
 def load() -> ctypes.CDLL:
@@ -218,19 +220,18 @@ def load() -> ctypes.CDLL:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        # incremental make keeps a cached .so in sync with newer sources
-        # (a stale library would miss newly-exported symbols); harmless
-        # no-op when up to date, ignored when only a prebuilt .so exists.
-        # An explicit BRPC_TPU_NATIVE_SO override is loaded AS IS — the
-        # soak driver builds its instrumented library itself.
+        # incremental make keeps the .so in sync with the sources (a
+        # no-op when up to date); a failed build is an error, never a
+        # reason to load whatever library is lying around. An explicit
+        # BRPC_TPU_NATIVE_SO override is loaded AS IS — the soak driver
+        # builds its instrumented library itself.
         if "BRPC_TPU_NATIVE_SO" in os.environ:
             if not os.path.exists(_SO_PATH):
                 raise NativeUnavailable(
                     "BRPC_TPU_NATIVE_SO points at a missing library: " +
                     _SO_PATH)
-        elif not _build() and not os.path.exists(_SO_PATH):
-            raise NativeUnavailable(
-                "native core not built and toolchain unavailable")
+        else:
+            _build()
         global _rss_at_load
         if _rss_at_load is None:
             _rss_at_load = _read_rss()
@@ -240,6 +241,7 @@ def load() -> ctypes.CDLL:
         lib.nat_sched_stop.restype = None
         lib.nat_sched_workers.restype = ctypes.c_int
         lib.nat_sched_switches.restype = ctypes.c_uint64
+        lib.nat_sched_trim_stacks.restype = ctypes.c_int
         lib.nat_bench_spawn_join.argtypes = [ctypes.c_int, ctypes.c_int]
         lib.nat_bench_spawn_join.restype = ctypes.c_uint64
         lib.nat_bench_ping_pong.argtypes = [ctypes.c_int]

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from brpc_tpu.jaxcompat import shard_map
-
 
 def make_mesh(axis_sizes: dict, devices=None) -> Mesh:
     """Build a Mesh from {axis_name: size}; sizes must multiply to the
@@ -58,8 +56,8 @@ def _allreduce_fn(mesh: Mesh, axis: str, shape: Tuple[int, ...], dtype, op: str)
 
     spec_in = P(axis)
     spec_out = P()  # replicated result
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=spec_in,
-                             out_specs=spec_out))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=spec_in,
+                                 out_specs=spec_out))
 
 
 def allreduce(mesh: Mesh, axis: str, x, op: str = "add"):
@@ -76,8 +74,8 @@ def _allgather_fn(mesh: Mesh, axis: str, shape, dtype):
     def local(x):
         return lax.all_gather(x, axis, axis=0, tiled=True)
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(), check=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P(), check_vma=False))
 
 
 def allgather(mesh: Mesh, axis: str, x):
@@ -95,8 +93,8 @@ def _reduce_scatter_fn(mesh: Mesh, axis: str, shape, dtype):
         out = lax.psum_scatter(x[0], axis, scatter_dimension=0, tiled=True)
         return out[None, :]
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis)))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P(axis)))
 
 
 def reduce_scatter(mesh: Mesh, axis: str, x):
@@ -115,8 +113,8 @@ def _ppermute_fn(mesh: Mesh, axis: str, shape, dtype, shift: int):
     def local(x):
         return lax.ppermute(x, axis, perm)
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis)))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P(axis)))
 
 
 def ring_shift(mesh: Mesh, axis: str, x, shift: int = 1):
@@ -136,8 +134,8 @@ def _all_to_all_fn(mesh: Mesh, axis: str, shape, dtype):
         # y: (N, 1, ...) -> (1, N, ...)
         return jnp.swapaxes(y, 0, 1)
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis)))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P(axis)))
 
 
 def all_to_all(mesh: Mesh, axis: str, x):

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from brpc_tpu.jaxcompat import shard_map
 from brpc_tpu.parallel import collectives
 
 
@@ -94,10 +93,10 @@ class MeshChannel:
                 raise ValueError(f"unknown merger {merger}")
 
             out_spec = P(axis) if merger is None else P()
-            run = jax.jit(shard_map(local, mesh=self.mesh,
-                                    in_specs=P(axis),
-                                    out_specs=out_spec,
-                                    check=False))
+            run = jax.jit(jax.shard_map(local, mesh=self.mesh,
+                                        in_specs=P(axis),
+                                        out_specs=out_spec,
+                                        check_vma=False))
             self._cache[key] = run
         x = jax.device_put(jnp.asarray(x),
                            NamedSharding(self.mesh, P(self.axis)))
@@ -125,9 +124,9 @@ class MeshChannel:
             def local(s):
                 return lax.ppermute(fn(s), axis, perm)
 
-            run = jax.jit(shard_map(local, mesh=self.mesh,
-                                    in_specs=P(axis),
-                                    out_specs=P(axis)))
+            run = jax.jit(jax.shard_map(local, mesh=self.mesh,
+                                        in_specs=P(axis),
+                                        out_specs=P(axis)))
             self._cache[key] = run
         x = jax.device_put(jnp.asarray(x),
                            NamedSharding(self.mesh, P(self.axis)))

@@ -26,6 +26,7 @@ Transfer semantics by locality:
 """
 from __future__ import annotations
 
+import functools
 import struct
 import threading
 import uuid
@@ -50,7 +51,7 @@ def _tune_allocator():
         libc = ctypes.CDLL("libc.so.6", use_errno=True)
         M_MMAP_THRESHOLD = -3
         libc.mallopt(M_MMAP_THRESHOLD, 256 << 20)
-    except Exception:
+    except (OSError, AttributeError):
         pass  # non-glibc platform: allocator stays stock
 
 
@@ -97,15 +98,13 @@ def local_device_info(arm_fabric: bool = False) -> dict:
         # wire, so import success alone is not proof enough
         "xfer": _global_xfer_server() is not None,
     }
-    try:
-        import jax
+    import jax
 
-        devs = jax.devices()
-        info["platform"] = devs[0].platform if devs else "none"
-        info["device_count"] = len(devs)
-    except Exception:
-        info["platform"] = "none"
-        info["device_count"] = 0
+    # a process that cannot reach its backend fails here, loudly: it
+    # must not pass for a device-less peer
+    devs = jax.devices()
+    info["platform"] = devs[0].platform
+    info["device_count"] = len(devs)
     return info
 
 
@@ -135,7 +134,6 @@ class DeviceBlockPool:
         self._device = device or jax.devices()[0]
         self._free: Dict[int, List] = {}
         self._lock = threading.Lock()
-        self._fill_fns = {}  # (size_class, nbytes) -> donating writer
         for size in self.SIZE_CLASSES:
             buffers = []
             for _ in range(blocks_per_class):
@@ -144,6 +142,11 @@ class DeviceBlockPool:
                 )
                 buffers.append(buf)
             self._free[size] = buffers
+
+    @property
+    def device(self):
+        """The device whose HBM the pool's blocks live in."""
+        return self._device
 
     def acquire(self, nbytes: int):
         """Returns (size_class, buffer) or None if exhausted/oversized."""
@@ -164,20 +167,6 @@ class DeviceBlockPool:
         with self._lock:
             return {k: len(v) for k, v in self._free.items()}
 
-    def _fill_fn(self, size_class: int, padded: int):
-        import jax
-
-        key = (size_class, padded)
-        with self._lock:
-            fn = self._fill_fns.get(key)
-        if fn is None:
-            fn = jax.jit(
-                lambda b, x: jax.lax.dynamic_update_slice(b, x, (0,)),
-                donate_argnums=(0,))
-            with self._lock:
-                self._fill_fns.setdefault(key, fn)
-        return fn
-
     @staticmethod
     def _pad_quantum(nbytes: int) -> int:
         # quantize the host-side staging length to powers of two so the
@@ -194,10 +183,14 @@ class DeviceBlockPool:
         then a device-side slice+bitcast produces the typed array. Falls
         back to a plain device_put when the pool is exhausted, the
         payload is oversized, or a different target device is asked for.
-        Returns a jax.Array of `np_dtype`/`shape`."""
+        Returns a jax.Array of `np_dtype`/`shape`; a dtype the device
+        would narrow (see `device_holds`) is refused."""
         import jax
         import numpy as np
 
+        if not device_holds(np_dtype):
+            raise ValueError(f"put_via_pool: {np.dtype(np_dtype)} would be "
+                             f"narrowed on the device (x64 is off)")
         nbytes = int(host_u8.size)
         target = device or self._device
         got = self.acquire(nbytes) if target == self._device else None
@@ -216,13 +209,9 @@ class DeviceBlockPool:
                 staged[nbytes:] = 0
             else:
                 staged = host_u8
-            filled = self._fill_fn(size_class, padded)(buf, staged)
-            itemsize = np.dtype(np_dtype).itemsize
-            head = filled[:nbytes]
-            if itemsize > 1:
-                head = jax.lax.bitcast_convert_type(
-                    head.reshape(-1, itemsize), np_dtype)
-            arr = head.reshape(shape)
+            fill, view = pool_programs()
+            filled = fill(buf, staged)
+            arr = view(filled, nbytes, np.dtype(np_dtype), tuple(shape))
             # the pooled buffer may be re-donated the moment it returns
             # to the freelist: the slice/bitcast read must be complete
             arr.block_until_ready()
@@ -240,6 +229,29 @@ class DeviceBlockPool:
                 self.release(size_class, jax.device_put(
                     jnp.zeros((size_class,), dtype=jnp.uint8),
                     self._device))
+
+
+@functools.lru_cache(maxsize=None)
+def pool_programs():
+    """The pool's two device programs, jitted once per process (jit keys
+    them by shape): `fill(block, staged)` writes staged bytes over the
+    head of a DONATED block, and `view(filled, nbytes, dtype, shape)`
+    slices and bitcasts the head into the typed array."""
+    import jax
+
+    fill = jax.jit(
+        lambda block, staged: jax.lax.dynamic_update_slice(
+            block, staged, (0,)),
+        donate_argnums=(0,))
+
+    def view(filled, nbytes, dtype, shape):
+        head = filled[:nbytes]
+        if dtype.itemsize > 1:
+            head = jax.lax.bitcast_convert_type(
+                head.reshape(-1, dtype.itemsize), dtype)
+        return head.reshape(shape)
+
+    return fill, jax.jit(view, static_argnums=(1, 2, 3))
 
 
 _default_pool: Optional[DeviceBlockPool] = None
@@ -288,12 +300,9 @@ class HostArena:
             # A non-owner must NOT let Python's resource tracker unlink
             # the segment when THIS process exits (3.12 has no track=False;
             # the tracker would otherwise destroy the owner's live arena).
-            try:
-                from multiprocessing import resource_tracker
+            from multiprocessing import resource_tracker
 
-                resource_tracker.unregister(self.shm._name, "shared_memory")
-            except Exception:
-                pass
+            resource_tracker.unregister(self.shm._name, "shared_memory")
             if prefault:
                 # attach side reads: fault the mapping in before the
                 # receive path timing matters
@@ -305,16 +314,13 @@ class HostArena:
         self.owner = create
 
     def _prefault(self, write: bool):
-        try:
-            import numpy as np
+        import numpy as np
 
-            view = np.frombuffer(self.shm.buf, dtype=np.uint8)
-            if write:
-                view[::4096] = 0  # one store per page
-            else:
-                int(view[::4096].sum())  # one load per page
-        except Exception:
-            pass  # numpy-less / exotic platform: pay the faults lazily
+        view = np.frombuffer(self.shm.buf, dtype=np.uint8)
+        if write:
+            view[::4096] = 0  # one store per page
+        else:
+            int(view[::4096].sum())  # one load per page
 
     # -- span allocator ----------------------------------------------------
     def alloc(self, nbytes: int) -> Optional[int]:
@@ -395,11 +401,8 @@ class HostArena:
             # cannot re-raise (the round-2 unraisable-BufferError leak
             # seam); the orphaned mmap object unmaps itself once the last
             # exported view dies — no leak, no warning.
-            try:
-                self.shm._buf = None
-                self.shm._mmap = None
-            except Exception:
-                pass
+            self.shm._buf = None
+            self.shm._mmap = None
         except OSError:
             pass
 
@@ -507,20 +510,16 @@ def fabric_set_sink(fn):
 def _fabric_arm_receiver() -> str:
     """Create (or adopt) this process's fabric segment and start the
     receiver drain thread. Returns the segment name, or '' when the
-    native runtime is unavailable / disabled (BRPC_TPU_FABRIC=0)."""
+    fabric is disabled (BRPC_TPU_FABRIC=0) or its segment cannot be
+    created. A native core that does not build raises NativeUnavailable."""
     global _fabric_name, _fabric_thread
     import os
 
     if os.environ.get("BRPC_TPU_FABRIC", "1") == "0":
         return ""
-    try:
-        from brpc_tpu import native
+    from brpc_tpu import native
 
-        if not native.available():
-            return ""
-        lib = native.load()
-    except Exception:
-        return ""
+    lib = native.load()
     with _fabric_lock:
         if _fabric_thread is not None and _fabric_thread.is_alive():
             name = lib.nat_shm_lane_name() or b""
@@ -547,6 +546,12 @@ def _fabric_drain_loop():
         try:
             lease = native.fabric_take(200)
         except Exception:
+            # the drain is a thread boundary: report why it stopped,
+            # or pushes fail later with "record never arrived"
+            import logging
+
+            logging.getLogger(__name__).exception(
+                "tensor fabric drain stopped")
             return
         now = time.monotonic()
         with _fabric_cv:
@@ -597,14 +602,9 @@ def _fabric_attach_producer(name: str) -> bool:
     shm-worker parent) or already attached elsewhere falls back to the
     shm-arena lane for other peers."""
     global _producer_target
-    try:
-        from brpc_tpu import native
+    from brpc_tpu import native
 
-        if not native.available():
-            return False
-        lib = native.load()
-    except Exception:
-        return False
+    lib = native.load()
     with _fabric_lock:
         if _producer_target is not None:
             return _producer_target == name
@@ -985,14 +985,13 @@ class DeviceEndpoint:
             entry = self._retained.pop(seq, None)
         if entry is not None:
             release, total = entry
-            if release is not None:
-                try:
+            try:
+                if release is not None:
                     release()
-                except Exception:
-                    pass
-            with self._window_cond:
-                self._inflight = max(0, self._inflight - total)
-                self._window_cond.notify_all()
+            finally:
+                with self._window_cond:
+                    self._inflight = max(0, self._inflight - total)
+                    self._window_cond.notify_all()
 
     @property
     def inflight_bytes(self) -> int:
@@ -1011,6 +1010,20 @@ def _recv_exact(fd, n: int) -> Optional[bytes]:
             return None
         out += chunk
     return out
+
+
+def device_holds(dtype) -> bool:
+    """True when a jax.Array can hold `dtype` exactly. With x64 off, JAX
+    narrows 64-bit types to 32 bits, which would change pushed values."""
+    import jax
+
+    return jax.dtypes.canonicalize_dtype(dtype) == dtype
+
+
+def _landing(device, dtype):
+    """Where a received tensor lands: `device`, unless the device would
+    narrow its dtype — such a tensor stays a host array, byte-exact."""
+    return device if device is not None and device_holds(dtype) else None
 
 
 def _np_dtype(name: str):
@@ -1113,7 +1126,9 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
     """Reconstruct arrays from a tensor-bearing message. Returns
     (arrays, ack_seq). Zero-copy when the sender published in-process;
     mapped straight out of the sender's shared arena when same-host (the
-    recv-zero-copy-into-registered-blocks path, rdma_endpoint.h:214-219)."""
+    recv-zero-copy-into-registered-blocks path, rdma_endpoint.h:214-219).
+    With `device`, each tensor lands there unless the device would narrow
+    its dtype (`device_holds`): that one stays a host array, byte-exact."""
     if not meta.tensors:
         return [], None
     spec = meta.tensors[0].sharding_spec or ""
@@ -1164,14 +1179,16 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
                     f"arrived (fabric receiver not draining?)")
             leases.append(lease)
         arrays = []
+        bound = set()  # leases now owned by a zero-copy host array
         try:
-            for t, lease in zip(meta.tensors, leases):
+            for i, (t, lease) in enumerate(zip(meta.tensors, leases)):
                 dtype = _np_dtype(t.dtype)
                 mv = lease.view()
-                if device is not None:
+                dev = _landing(device, dtype)
+                if dev is not None:
                     arr = default_block_pool().put_via_pool(
                         np.frombuffer(mv, dtype=np.uint8), dtype,
-                        tuple(t.shape), device)
+                        tuple(t.shape), dev)
                 else:
                     # zero-copy: the array IS the arena span; the lease
                     # releases when the last view of it is collected.
@@ -1182,6 +1199,7 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
                     # reshape wrapper would let a slice outlive the span.
                     flat = np.frombuffer(mv, dtype=dtype)
                     _bind_lease(flat, lease)
+                    bound.add(i)
                     arr = flat.reshape(tuple(t.shape))
                 arrays.append(arr)
         finally:
@@ -1191,7 +1209,8 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
                 # the async H2D copies must finish before the spans are
                 # handed back to the producer's reclaim
                 jax.block_until_ready(arrays)
-                for lease in leases:
+            for i, lease in enumerate(leases):
+                if i not in bound:
                     lease.release()
         return arrays, seq
     if parts[0] == "inproc" and parts[1].isdigit():
@@ -1202,6 +1221,16 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
             # an empty attachment, so fail loudly.
             raise ValueError(f"device transport: in-process ticket "
                              f"{parts[1]} is no longer claimable")
+        if device is not None:
+            import jax
+
+            # arrays already on the device pass through as the SAME
+            # object; host arrays the sender published land in HBM
+            arrays = [a if (isinstance(a, jax.Array)
+                            and a.devices() == {device})
+                      or _landing(device, a.dtype) is None
+                      else jax.device_put(a, device) for a in arrays]
+            jax.block_until_ready(arrays)
         return arrays, seq
     if parts[0] == "shm" and len(parts) == 4:
         arena = attach_arena(parts[1])
@@ -1219,12 +1248,13 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
             view = np.frombuffer(arena.shm.buf, dtype=np.uint8,
                                  count=t.nbytes, offset=pos)
             pos += t.nbytes
-            if device is not None:
+            dev = _landing(device, dtype)
+            if dev is not None:
                 # host->device DMA from the mapped arena THROUGH the
                 # device block pool (block_pool.h role: transfer bytes
                 # land in pooled, pre-allocated HBM)
                 arr = default_block_pool().put_via_pool(
-                    view, dtype, tuple(t.shape), device)
+                    view, dtype, tuple(t.shape), dev)
             else:
                 # own the bytes before ACK lets the sender reuse them
                 arr = np.array(view.view(dtype).reshape(tuple(t.shape)))
@@ -1243,12 +1273,13 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
     arrays = []
     for t in meta.tensors:
         raw = attachment.cutn_bytes(t.nbytes)
-        if device is not None:
+        dtype = _np_dtype(t.dtype)
+        dev = _landing(device, dtype)
+        if dev is not None:
             arr = default_block_pool().put_via_pool(
-                np.frombuffer(raw, dtype=np.uint8), _np_dtype(t.dtype),
-                tuple(t.shape), device)
+                np.frombuffer(raw, dtype=np.uint8), dtype,
+                tuple(t.shape), dev)
         else:
-            arr = np.frombuffer(raw, dtype=_np_dtype(t.dtype)).reshape(
-                tuple(t.shape))
+            arr = np.frombuffer(raw, dtype=dtype).reshape(tuple(t.shape))
         arrays.append(arr)
     return arrays, seq

@@ -321,7 +321,9 @@ class NativeRuntimeMount:
         name = lib.nat_shm_lane_name().decode()
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
-        env = dict(os.environ)
+        # workers are host-only: the server process may hold the chip,
+        # and a chip belongs to one process
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["PYTHONPATH"] = repo_root + os.pathsep + \
             env.get("PYTHONPATH", "")
         self._shm_workers = [

@@ -330,13 +330,18 @@ class Socket:
                 return 0  # current writer will flush us
             self._writing = True
         # We are the writer: one inline attempt on this thread, then hand
-        # off to a KeepWrite task (socket.cpp:1287-1305,1585).
-        if not self._flush_some():
+        # off to a KeepWrite task (socket.cpp:1287-1305,1585). The inline
+        # attempt ends with our own message: draining what other threads
+        # keep queuing behind it would hold this caller for as long as
+        # they write (a stream writer on the shared connection held an
+        # RPC caller for its whole 2 s storm).
+        if not self._flush_some(until=req):
             start_background(self._keep_write)
         return 0
 
-    def _flush_some(self) -> bool:
-        """Write until drained (True) or would-block (False)."""
+    def _flush_some(self, until: Optional[_WriteRequest] = None) -> bool:
+        """Write until drained (True), or would-block or `until` written
+        with more queued (False: the writer role passes to KeepWrite)."""
         while True:
             with self._write_lock:
                 if not self._write_q:
@@ -364,6 +369,8 @@ class Socket:
                 with self._write_lock:
                     if self._write_q and self._write_q[0] is req:
                         self._write_q.popleft()
+                    if req is until and self._write_q:
+                        return False
             elif n == 0:
                 return False
 

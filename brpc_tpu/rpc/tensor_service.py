@@ -3,9 +3,11 @@
 The rdma_performance-shaped surface (SURVEY.md section 2.9 +
 example/rdma_performance/): a TensorStore service accepts pushed tensors
 and serves pulls; tensors ride the tpu_std attachment described by
-RpcMeta.tensors, zero-copy in process (the loopback-ICI stand-in) and as
-bytes across processes (FALLBACK_TCP path), via
-brpc_tpu.rpc.device_transport.
+RpcMeta.tensors, zero-copy in process (the loopback-ICI stand-in) and
+through the descriptor-ring fabric, a shared arena or the wire across
+processes, via brpc_tpu.rpc.device_transport. Whatever the lane, a push
+lands on the server's device, except a dtype the device would narrow
+(64-bit types with x64 off), which is held on the host unchanged.
 
 Server-side handshake counterpart: the TDEV protocol below answers the
 DeviceEndpoint.app_connect handshake on accepted connections, so both ends
@@ -17,12 +19,13 @@ from __future__ import annotations
 import json
 import struct
 import threading
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.rpc import errors
 from brpc_tpu.rpc.device_transport import (
     DeviceEndpoint,
+    default_block_pool,
     local_device_info,
     receive_tensors,
 )
@@ -113,14 +116,15 @@ register_protocol(Protocol(
 # -- the store service ------------------------------------------------------
 
 class TensorStoreService(Service):
-    """In-memory named tensor store — push/pull over RPC."""
+    """Named tensor store — push/pull over RPC. Pushed tensors are held
+    on the server's device; a dtype the device would narrow stays on the
+    host, byte-exact (`device_transport.device_holds`)."""
 
     SERVICE_NAME = "TensorStore"
 
-    def __init__(self, on_push: Optional[Callable[[str, List], None]] = None):
+    def __init__(self):
         self._store: Dict[str, List] = {}
         self._lock = threading.Lock()
-        self._on_push = on_push
 
     @rpc_method(ts_pb2.TensorPushRequest, ts_pb2.TensorPushResponse)
     def Push(self, cntl, request, response, done):
@@ -129,14 +133,12 @@ class TensorStoreService(Service):
             cntl.set_failed(errors.EREQUEST, "no tensors in request")
             done()
             return
-        arrays, seq = receive_tensors(meta, cntl.request_attachment)
+        # every lane lands on the server's device, in the HBM the
+        # block pool owns (narrowed dtypes excepted: they stay exact)
+        arrays, seq = receive_tensors(meta, cntl.request_attachment,
+                                      device=default_block_pool().device)
         with self._lock:
             self._store[request.name] = arrays
-        if self._on_push is not None:
-            try:
-                self._on_push(request.name, arrays)
-            except Exception:
-                pass
         response.ok = True
         response.ack_seq = seq or 0
         done()

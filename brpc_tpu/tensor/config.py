@@ -62,3 +62,24 @@ class MeshSpec:
             i += 1
         sizes["dp"] *= n  # odd remainder rides dp
         return cls(**sizes)
+
+
+# The flagship at its bench width: compute-bound on one v5e chip and
+# within its 16 GB (the step takes ~1.4 GiB of arguments and ~4.6 GiB of
+# temporaries), at FLAGSHIP_BATCH = (batch, sequence) per step.
+FLAGSHIP = ModelConfig(vocab=32768, d_model=2048, n_heads=16, d_head=128,
+                       d_ff=8192, n_layers=8, n_experts=2,
+                       expert_capacity_factor=1.25)
+FLAGSHIP_BATCH = (4, 512)
+
+
+def flagship_flops_per_step(cfg: ModelConfig, batch: int, seq: int) -> float:
+    """Model FLOPs of one training step (forward matmuls x3 for fwd+bwd).
+    Conservative: the MoE one-hot dispatch einsums burn FLOPs that are
+    not model FLOPs and are not counted. Per token and layer: attention
+    projections 2*4*d*dqkv, scores 2*2*T*dqkv, top-1 MoE 2*2*d*d_ff;
+    per token: unembedding 2*d*vocab."""
+    d, dq, L = cfg.d_model, cfg.d_qkv, cfg.n_layers
+    fwd_per_tok = (L * (2 * 4 * d * dq + 2 * 2 * seq * dq +
+                        2 * 2 * d * cfg.d_ff) + 2 * d * cfg.vocab)
+    return 3.0 * fwd_per_tok * batch * seq

@@ -24,7 +24,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from brpc_tpu.jaxcompat import shard_map as compat_shard_map
 from brpc_tpu.tensor.config import MeshSpec, ModelConfig
 from brpc_tpu.tensor.moe import MoEParams, init_moe, moe_layer
 from brpc_tpu.tensor.pipeline import spmd_pipeline
@@ -221,9 +220,9 @@ def _shard_map(fn, mesh, in_specs, out_specs):
     """shard_map with replication checking off: masked psum broadcasts and
     all_to_all-replicated values are mathematically replicated but opaque to
     the checker."""
-    return compat_shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check=False
-    )
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)
 
 
 def make_spmd_forward(cfg: ModelConfig, spec: MeshSpec, n_microbatches: int = 1):

@@ -14,10 +14,6 @@ import time
 
 sys.path.insert(0, ".")
 
-import _jaxenv  # noqa: E402
-
-_jaxenv.apply()
-
 import numpy as np  # noqa: E402
 
 from brpc_tpu import bvar, rpc  # noqa: E402
@@ -45,7 +41,7 @@ def main():
     bytes_moved = bvar.Adder("device_perf_bytes")
     errors = bvar.Adder("device_perf_errors")
     payload = np.random.default_rng(0).standard_normal(
-        (args.mb * 1024 * 1024) // 8).astype(np.float64)
+        (args.mb * 1024 * 1024) // 4).astype(np.float32)
 
     def pusher(tid: int):
         ch = make_device_channel(target)

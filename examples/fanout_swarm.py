@@ -26,7 +26,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-import _jaxenv  # noqa: F401,E402  (pins jax to cpu for the demo)
 
 from brpc_tpu import native  # noqa: E402
 from brpc_tpu.rpc.combo_channels import PartitionChannel  # noqa: E402

@@ -9,10 +9,6 @@ import sys
 
 sys.path.insert(0, ".")
 
-import _jaxenv  # noqa: E402
-
-_jaxenv.apply()
-
 from brpc_tpu import rpc  # noqa: E402
 from brpc_tpu.rpc.proto import echo_pb2  # noqa: E402
 

@@ -12,10 +12,6 @@ import time
 
 sys.path.insert(0, ".")
 
-import _jaxenv  # noqa: E402
-
-_jaxenv.apply()
-
 import numpy as np  # noqa: E402
 
 from brpc_tpu import rpc  # noqa: E402
@@ -67,55 +63,46 @@ def main():
                                np.asarray(arr)[:8])
     print("pull verified")
 
-    # cross-process lane: a subprocess server, payloads via the shared
-    # HostArena (descriptor-only wire — the rdma_performance shape)
+    # cross-process lane: a client child pushes into THIS server, which
+    # holds the device. The child is host-only (JAX_PLATFORMS=cpu): a
+    # chip belongs to one process. Payloads ride the descriptor-ring
+    # fabric into our arena and land in HBM (rdma_performance shape).
+    import os
     import subprocess
-    import sys as _sys
 
     script = (
-        "import sys; sys.path.insert(0, '.');\n"
-        "import _jaxenv; _jaxenv.apply()\n"
-        "from brpc_tpu import rpc\n"
-        "from brpc_tpu.rpc.tensor_service import TensorStoreService\n"
-        "srv = rpc.Server(rpc.ServerOptions(num_threads=2))\n"
-        "srv.add_service(TensorStoreService())\n"
-        "assert srv.start('127.0.0.1:0') == 0\n"
-        "print(srv.listen_endpoint.port, flush=True)\n"
-        "sys.stdin.readline()\n"
-        "srv.stop()\n"
+        "import sys, time; sys.path.insert(0, '.')\n"
+        "import numpy as np\n"
+        "from brpc_tpu.rpc import device_transport as dt\n"
+        "from brpc_tpu.rpc.tensor_service import (TensorClient,\n"
+        "                                         make_device_channel)\n"
+        "port, nbytes, iters = map(int, sys.argv[1:4])\n"
+        "client = TensorClient(make_device_channel(f'127.0.0.1:{port}'))\n"
+        "arr = np.zeros(nbytes // 4, np.float32)\n"
+        "cntl, _ = client.push('xwarm', [arr])\n"
+        "assert not cntl.failed(), cntl.error_text\n"
+        "lanes0 = dt.lane_counters()\n"
+        "t0 = time.perf_counter()\n"
+        "for i in range(iters):\n"
+        "    cntl, _ = client.push(f'x{i}', [arr])\n"
+        "    assert not cntl.failed(), cntl.error_text\n"
+        "dt_s = time.perf_counter() - t0\n"
+        "lanes1 = dt.lane_counters()\n"
+        "lane = [k for k in lanes1 if lanes1[k] > lanes0[k]]\n"
+        "print(f'{dt_s} {lane}', flush=True)\n"
     )
-    proc = subprocess.Popen([_sys.executable, "-c", script],
-                            stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                            text=True, cwd=".",
-                            env={**__import__('os').environ,
-                                 "PYTHONPATH": "examples"})
-    xport = int(proc.stdout.readline())
-    xch = make_device_channel(f"127.0.0.1:{xport}")
-    xclient = TensorClient(xch)
-    from brpc_tpu.rpc import device_transport as _dt
-
-    lanes0 = _dt.lane_counters()
-    cntl, _ = xclient.push("xwarm", [arr])
-    assert not cntl.failed(), cntl.error_text
-    ep = cntl._current_sock.app_state
-    t0 = time.perf_counter()
-    for i in range(args.iters):
-        cntl, _ = xclient.push(f"x{i}", [arr])
-        assert not cntl.failed(), cntl.error_text
-    dtx = time.perf_counter() - t0
-    lanes1 = _dt.lane_counters()
-    # this process hosted its own in-process server above, so it owns a
-    # fabric segment of its own — the push falls back to the shared
-    # HostArena lane; a pure client process would ride the ring fabric
-    lane = next((k for k in ("ring", "shm", "wire")
-                 if lanes1[k] > lanes0[k]), "?")
+    res = subprocess.run(
+        [sys.executable, "-c", script, str(srv.listen_endpoint.port),
+         str(nbytes), str(args.iters)],
+        capture_output=True, text=True, timeout=120, cwd=".",
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert res.returncode == 0, res.stderr
+    dtx, lane = res.stdout.split(maxsplit=1)
+    dtx = float(dtx)
+    stored = svc.get(f"x{args.iters - 1}")[0]
     print(f"cross-process pushed {args.iters} x {args.mb}MB in {dtx:.3f}s "
           f"-> {nbytes * args.iters / dtx / 1e9:.2f} GB/s "
-          f"({lane} lane, same_host={ep.same_host}, "
-          f"same_process={ep.same_process})")
-    xch.close()
-    proc.stdin.close()
-    proc.wait(timeout=10)
+          f"(lanes {lane.strip()}, stored on {stored.devices()})")
 
     import jax
 

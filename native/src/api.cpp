@@ -27,6 +27,8 @@ uint64_t nat_sched_switches() {
   return Scheduler::instance()->total_switches();
 }
 
+int nat_sched_trim_stacks() { return (int)sched_trim_stack_pool(); }
+
 // spawn N fibers each incrementing a shared counter M times with yields;
 // returns the final counter (correctness probe for spawn/steal/yield).
 static std::atomic<uint64_t> g_counter{0};

@@ -7,6 +7,7 @@
 #include <stdlib.h>
 #include <unistd.h>
 #include <algorithm>
+#include <vector>
 
 namespace brpc_tpu {
 

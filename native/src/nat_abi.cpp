@@ -265,6 +265,7 @@ int main() {
       NAT_SYM(nat_sched_stop),
       NAT_SYM(nat_sched_workers),
       NAT_SYM(nat_sched_switches),
+      NAT_SYM(nat_sched_trim_stacks),
       NAT_SYM(nat_bench_spawn_join),
       NAT_SYM(nat_bench_ping_pong),
       NAT_SYM(nat_wsq_selftest),

@@ -48,6 +48,7 @@ int nat_sched_start(int nworkers);
 void nat_sched_stop(void);
 int nat_sched_workers(void);
 uint64_t nat_sched_switches(void);
+int nat_sched_trim_stacks(void);
 uint64_t nat_bench_spawn_join(int nfibers, int rounds);
 double nat_bench_ping_pong(int rounds);
 int nat_wsq_selftest(void);

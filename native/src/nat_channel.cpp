@@ -572,6 +572,35 @@ static int call_attempt(NatChannel* ch, NatSocket* s, const char* service,
   return rc;
 }
 
+// A retry after a socket failure must re-dial, not re-address the dying
+// socket: set_failed raises `failed` first and unregisters the id last,
+// and an attempt inside that window fails again at once — max_retry
+// would burn out in microseconds against a peer that is already back.
+// Wait (1 ms steps, within the call's deadline) for the unregister.
+// The window is load-bearing: set_failed's fail_all sweeps EVERY pending
+// slot of the channel, so a replacement dialed before the sweep ends
+// would have its own calls swept, and one published before set_failed's
+// `sock_id == id` check would leave the dying socket's calls unswept.
+// The wait sits here, not in channel_socket: completion callbacks run
+// inside fail_all and may issue calls on this channel from that thread.
+static void wait_failed_socket_gone(NatChannel* ch, int64_t deadline_us) {
+  for (int i = 0; i < 200; i++) {
+    NatSocket* s = sock_address(ch->sock_id.load(std::memory_order_acquire));
+    if (s == nullptr) return;
+    bool failed = s->failed.load(std::memory_order_acquire);
+    NAT_REF_RELEASE(s, sock.borrow);
+    if (!failed) return;
+    if (deadline_us != 0 &&
+        std::chrono::duration_cast<std::chrono::microseconds>(
+            std::chrono::steady_clock::now().time_since_epoch())
+                .count() >= deadline_us) {
+      return;
+    }
+    struct timespec ts = {0, 1000 * 1000};
+    nanosleep(&ts, nullptr);
+  }
+}
+
 // Synchronous call. Returns 0 on success (out buffers malloc'd, caller
 // frees with nat_buf_free), else an error code. timeout_ms > 0 arms a
 // deadline covering ALL attempts (reference semantics); failed-socket
@@ -670,6 +699,7 @@ int nat_channel_call_full(void* h, const char* service, const char* method,
       free(*err_text_out);  // superseded by the retry
       *err_text_out = nullptr;
     }
+    if (rc == kEFAILEDSOCKET) wait_failed_socket_gone(ch, deadline_us);
   }
 }
 

@@ -437,7 +437,10 @@ void NatSocket::arm_epollout() {
   std::lock_guard g(epollctl_mu);
   if (failed.load(std::memory_order_acquire)) return;
   uint32_t want = EPOLLIN | EPOLLET | EPOLLOUT;
-  if (epoll_events == want) return;
+  // MOD even when EPOLLOUT is already armed: it re-evaluates readiness,
+  // so a socket that is writable NOW reports a fresh edge. A park after
+  // an EAGAIN/EINTR that did not leave the buffer full (a signal, an
+  // injected fault) would otherwise wait for an edge that never comes.
   struct epoll_event ev;
   ev.events = want;
   ev.data.u64 = id;

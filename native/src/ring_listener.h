@@ -33,43 +33,6 @@
 #include <vector>
 #include "nat_lockrank.h"
 
-// uAPI compat: pre-5.19 build hosts lack the provided-buffer-ring ABI in
-// <linux/io_uring.h>. The values below are the kernel wire ABI (not host
-// header definitions), and init() probes actual kernel support at runtime
-// — on old kernels the setup syscall fails and the runtime stays on epoll.
-// Probe on IORING_OFF_PBUF_RING: it is a #define in every header that has
-// the pbuf-ring ABI, whereas IORING_REGISTER_PBUF_RING is an enum member
-// there (an #ifndef on it would redefine the structs on modern headers).
-#ifndef IORING_OFF_PBUF_RING
-#define IORING_REGISTER_PBUF_RING 22
-struct io_uring_buf {
-  __u64 addr;
-  __u32 len;
-  __u16 bid;
-  __u16 resv;
-};
-struct io_uring_buf_reg {
-  __u64 ring_addr;
-  __u32 ring_entries;
-  __u16 bgid;
-  __u16 flags;
-  __u64 resv[3];
-};
-#endif
-#ifndef IORING_RECV_MULTISHOT
-#define IORING_RECV_MULTISHOT (1U << 1)
-#endif
-// SQPOLL ABI (5.1+; values are kernel wire ABI, probed at runtime)
-#ifndef IORING_SETUP_SQPOLL
-#define IORING_SETUP_SQPOLL (1U << 1)
-#endif
-#ifndef IORING_SQ_NEED_WAKEUP
-#define IORING_SQ_NEED_WAKEUP (1U << 0)
-#endif
-#ifndef IORING_ENTER_SQ_WAKEUP
-#define IORING_ENTER_SQ_WAKEUP (1U << 1)
-#endif
-
 namespace brpc_tpu {
 
 // One harvested completion, handed from the poller to a worker

@@ -139,6 +139,22 @@ static void free_stack(char* stack, size_t size) {
   munmap(stack - 4096, size + 4096);
 }
 
+size_t sched_trim_stack_pool() {
+  char* cached[kStackPoolCap];
+  size_t n;
+  {
+    std::lock_guard g(g_stack_pool_mu);
+    n = g_stack_pool_n;
+    for (size_t i = 0; i < n; i++) cached[i] = g_stack_pool[i];
+    g_stack_pool_n = 0;
+  }
+  for (size_t i = 0; i < n; i++) {
+    NAT_RES_FREE(NR_SCHED_STACK, kStackSize + 4096, cached[i] - 4096);
+    munmap(cached[i] - 4096, kStackSize + 4096);
+  }
+  return n;
+}
+
 void Worker::signal() {
   // seq_cst store-then-load pairs with the waiter's parked-then-recheck
   // (Dekker): either we see parked > 0 and notify, or the waiter's

@@ -220,4 +220,9 @@ class Scheduler {
   void run_fiber(Worker* w, Fiber* f);
 };
 
+// Unmaps every stack cached in the pool (the pool refills on demand), so
+// the ledger's sched.stack row counts the fibers alive now rather than
+// the process's past concurrency peak. Returns the number unmapped.
+size_t sched_trim_stack_pool();
+
 }  // namespace brpc_tpu

@@ -6,7 +6,6 @@ with retention-until-ACK observed on both sides."""
 import os
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -18,8 +17,6 @@ from brpc_tpu.rpc.tensor_service import TensorClient, make_device_channel
 SERVER_SCRIPT = r"""
 import sys
 sys.path.insert(0, ".")
-import jax
-jax.config.update("jax_platforms", "cpu")
 
 from brpc_tpu import rpc
 from brpc_tpu.rpc.tensor_service import TensorStoreService
@@ -78,53 +75,77 @@ def remote_store():
     proc.wait(timeout=10)
 
 
+RING_CLIENT_SCRIPT = r"""
+import sys
+sys.path.insert(0, ".")
+import numpy as np
+from brpc_tpu.rpc import device_transport as dt
+from brpc_tpu.rpc.tensor_service import TensorClient, make_device_channel
+
+ch = make_device_channel(f"127.0.0.1:{sys.argv[1]}")
+client = TensorClient(ch)
+
+ring0 = dt._dev_ring.get_value()
+wire0 = dt._dev_wire.get_value()
+
+arr = np.arange(4096, dtype=np.float32).reshape(64, 64)
+cntl, resp = client.push("w", [arr])
+assert not cntl.failed(), cntl.error_text
+assert resp.ok
+
+sock = cntl._current_sock
+ep = sock.app_state
+assert isinstance(ep, dt.DeviceEndpoint)
+assert ep.state == dt.ESTABLISHED
+assert not ep.same_process and ep.same_host
+# the server advertised its fabric and the push used it — no wire
+# payload, no send-arena staging
+assert ep.peer_info.get("fabric"), "server did not advertise a fabric"
+assert dt._dev_ring.get_value() == ring0 + 1
+assert dt._dev_wire.get_value() == wire0
+assert len(cntl.request_attachment) == 0  # no payload bytes on the wire
+# push response piggybacked the ACK: retention drained, window open
+assert ep.retained_count == 0
+assert ep.inflight_bytes == 0
+
+cntl2, pulled = client.pull("w")
+assert not cntl2.failed(), cntl2.error_text
+np.testing.assert_array_equal(pulled[0], arr)
+assert len(cntl2.response_attachment) == 0
+
+# multi-tensor pushes ride one record per tensor
+arrs = [np.full((32, 32), i, dtype=np.int32) for i in range(3)]
+cntl3, resp3 = client.push("multi", arrs)
+assert not cntl3.failed(), cntl3.error_text
+assert dt._dev_ring.get_value() == ring0 + 2
+cntl4, pulled4 = client.pull("multi")
+assert not cntl4.failed(), cntl4.error_text
+for i in range(3):
+    np.testing.assert_array_equal(pulled4[i], arrs[i])
+
+# int64 above 2^31 and float64 come back unchanged: the server keeps
+# the dtypes its device would narrow on the host
+wide = [np.array([2**40 + 3, -(2**33)], dtype=np.int64),
+        np.array([1 + 2**-40], dtype=np.float64)]
+cntl5, _ = client.push("wide", wide)
+assert not cntl5.failed(), cntl5.error_text
+cntl6, pulled6 = client.pull("wide")
+assert not cntl6.failed(), cntl6.error_text
+for got, want in zip(pulled6, wide):
+    assert got.dtype == want.dtype, got.dtype
+    np.testing.assert_array_equal(got, want)
+
+ch.close()
+print("OK", flush=True)
+"""
+
+
 def test_two_process_ring_transfer(remote_store):
     """Push+pull to a DIFFERENT process rides the descriptor-ring fabric
     by default (ISSUE 15): the payload is written once into the
     receiver's blob arena as kind-8 records, zero payload bytes in the
     attachment, and the receiver consumes the spans in place."""
-    port = remote_store
-    ch = make_device_channel(f"127.0.0.1:{port}")
-    client = TensorClient(ch)
-
-    ring0 = dt._dev_ring.get_value()
-    wire0 = dt._dev_wire.get_value()
-
-    arr = np.arange(4096, dtype=np.float32).reshape(64, 64)
-    cntl, resp = client.push("w", [arr])
-    assert not cntl.failed(), cntl.error_text
-    assert resp.ok
-
-    sock = cntl._current_sock
-    ep = sock.app_state
-    assert isinstance(ep, dt.DeviceEndpoint)
-    assert ep.state == dt.ESTABLISHED
-    assert not ep.same_process and ep.same_host
-    # the server advertised its fabric and the push used it — no wire
-    # payload, no send-arena staging
-    assert ep.peer_info.get("fabric"), "server did not advertise a fabric"
-    assert dt._dev_ring.get_value() == ring0 + 1
-    assert dt._dev_wire.get_value() == wire0
-    assert len(cntl.request_attachment) == 0  # no payload bytes on the wire
-    # push response piggybacked the ACK: retention drained, window open
-    assert ep.retained_count == 0
-    assert ep.inflight_bytes == 0
-
-    cntl2, pulled = client.pull("w")
-    assert not cntl2.failed(), cntl2.error_text
-    np.testing.assert_array_equal(pulled[0], arr)
-    assert len(cntl2.response_attachment) == 0
-
-    # multi-tensor pushes ride one record per tensor
-    arrs = [np.full((32, 32), i, dtype=np.int32) for i in range(3)]
-    cntl3, resp3 = client.push("multi", arrs)
-    assert not cntl3.failed(), cntl3.error_text
-    cntl4, pulled4 = client.pull("multi")
-    assert not cntl4.failed(), cntl4.error_text
-    for i in range(3):
-        np.testing.assert_array_equal(pulled4[i], arrs[i])
-
-    ch.close()
+    _run_client(RING_CLIENT_SCRIPT, remote_store)
 
 
 FABRIC_OFF_SERVER_SCRIPT = SERVER_SCRIPT.replace(
@@ -157,33 +178,64 @@ def test_two_process_shm_arena_fallback():
         assert len(cntl.request_attachment) == 0
         cntl2, pulled = client.pull("w")
         np.testing.assert_array_equal(pulled[0], arr)
+        # int64 above 2^31 comes back unchanged through the arena
+        wide = np.array([2**40 + 3, -(2**33)], dtype=np.int64)
+        cntl3, _ = client.push("wide", [wide])
+        assert not cntl3.failed(), cntl3.error_text
+        assert dt._dev_shm.get_value() == shm0 + 2
+        cntl4, pulled4 = client.pull("wide")
+        assert pulled4[0].dtype == np.int64
+        np.testing.assert_array_equal(pulled4[0], wide)
         ch.close()
     finally:
         proc.stdin.close()
         proc.wait(timeout=10)
 
 
+WINDOW_CLIENT_SCRIPT = r"""
+import sys, time
+sys.path.insert(0, ".")
+import numpy as np
+from brpc_tpu.rpc import device_transport as dt
+from brpc_tpu.rpc.tensor_service import TensorClient, make_device_channel
+
+ch = make_device_channel(f"127.0.0.1:{sys.argv[1]}")
+client = TensorClient(ch)
+arena = dt.default_send_arena()
+free0 = arena.free_bytes()
+for i in range(8):
+    arr = np.full((256, 256), i, dtype=np.float32)
+    cntl, resp = client.push(f"t{i}", [arr])
+    assert not cntl.failed(), cntl.error_text
+# every push was acked synchronously -> every span freed
+deadline = time.monotonic() + 5
+while arena.free_bytes() != free0 and time.monotonic() < deadline:
+    time.sleep(0.05)
+assert arena.free_bytes() == free0
+cntl, pulled = client.pull("t7")
+np.testing.assert_array_equal(pulled[0], np.full((256, 256), 7,
+                                                 dtype=np.float32))
+ch.close()
+print("OK", flush=True)
+"""
+
+
+def _run_client(script, port):
+    """Run a push/pull client in a FRESH process: a process maps one
+    fabric segment, so a client attached here as a producer would change
+    what later tests in this worker find (and vice versa)."""
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    res = subprocess.run([sys.executable, "-c", script, str(port)],
+                         capture_output=True, text=True, timeout=120,
+                         cwd=repo_root)
+    assert res.returncode == 0 and res.stdout.strip() == "OK", \
+        res.stdout + res.stderr
+
+
 def test_two_process_window_retention(remote_store):
     """Several in-flight pushes exercise the sliding window + retention
     across the process boundary; all spans release after the ACKs."""
-    port = remote_store
-    ch = make_device_channel(f"127.0.0.1:{port}")
-    client = TensorClient(ch)
-    arena = dt.default_send_arena()
-    free0 = arena.free_bytes()
-    for i in range(8):
-        arr = np.full((256, 256), i, dtype=np.float32)
-        cntl, resp = client.push(f"t{i}", [arr])
-        assert not cntl.failed(), cntl.error_text
-    # every push was acked synchronously -> every span freed
-    deadline = time.monotonic() + 5
-    while arena.free_bytes() != free0 and time.monotonic() < deadline:
-        time.sleep(0.05)
-    assert arena.free_bytes() == free0
-    cntl, pulled = client.pull("t7")
-    np.testing.assert_array_equal(pulled[0], np.full((256, 256), 7,
-                                                     dtype=np.float32))
-    ch.close()
+    _run_client(WINDOW_CLIENT_SCRIPT, remote_store)
 
 
 def test_wire_fallback_still_works():
@@ -246,8 +298,6 @@ XFER_SERVER_SCRIPT = r"""
 import os, sys
 os.environ["BRPC_TPU_FAKE_XFER"] = "1"
 sys.path.insert(0, ".")
-import jax
-jax.config.update("jax_platforms", "cpu")
 
 from brpc_tpu import rpc
 from brpc_tpu.rpc.tensor_service import TensorStoreService

@@ -1,9 +1,12 @@
 """Smoke-run every example as a subprocess — the examples double as
 integration tests, as the reference's example/ suite does in its CI."""
+import os
 import subprocess
 import sys
 
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 EXAMPLES = [
     ("examples/echo.py", ["demo"]),
@@ -47,7 +50,7 @@ def test_example_runs(script, args):
     for attempt in (1, 2):
         proc = subprocess.run(
             [sys.executable, script, *args],
-            capture_output=True, text=True, timeout=180, cwd="/root/repo",
+            capture_output=True, text=True, timeout=180, cwd=REPO,
         )
         if proc.returncode == 0:
             return

@@ -497,8 +497,7 @@ def test_mesh_channel_host_axis(swarm_server):
 
     mesh = collectives.make_mesh({"dp": len(jax.devices())})
     mc = MeshChannel(mesh, "dp")
-    # device axis: the fused-collective lowering (brpc_tpu.jaxcompat
-    # resolves the jax.shard_map location/kwarg drift)
+    # device axis: the fused-collective lowering
     out = mc.parallel_call(lambda x: x * 2, np.ones(8, np.float32),
                            merger="add")
     assert float(out[0]) == 2.0 * len(jax.devices())

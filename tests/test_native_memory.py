@@ -146,6 +146,10 @@ def test_churn_balance_dial_call_close():
             end = time.time() + deadline_s
             while time.time() < end:
                 time.sleep(1.0)
+                # unmap the cached fiber stacks: sched.stack then counts
+                # the fibers alive now, not the process's concurrency peak
+                # (which other tests in this process set)
+                lib.nat_sched_trim_stacks()
                 rows = _res_rows()
                 cur = tuple(rows[s]["live_objects"]
                             for s in ("cluster", "srv.pyreq",
@@ -153,6 +157,7 @@ def test_churn_balance_dial_call_close():
                 if cur == prev:
                     return rows
                 prev = cur
+            lib.nat_sched_trim_stacks()
             return _res_rows()
 
         series = []
@@ -369,11 +374,23 @@ def test_growth_native_page_windows(console):
 
 
 def test_heap_growth_python_pages(console):
+    import tracemalloc
+
+    from brpc_tpu.builtin import profilers
+
     srv, _ = console
-    status, body, _ = _get(srv, "/heap")
-    assert status == 200 and "heap profile" in body
-    status, body, _ = _get(srv, "/growth")
-    assert status == 200 and "growth profile" in body
+    try:
+        status, body, _ = _get(srv, "/heap")
+        assert status == 200 and "heap profile" in body
+        status, body, _ = _get(srv, "/growth")
+        assert status == 200 and "growth profile" in body
+    finally:
+        # /heap starts tracemalloc; left on, it slows every later Python
+        # allocation in this worker (test_stream_throughput_sanity's echo
+        # fell from 0.19 to 0.04 GB/s, under its floor)
+        tracemalloc.stop()
+        with profilers._baseline_lock:
+            profilers._growth_baseline = None
 
 
 def test_heap_native_one_window_503():

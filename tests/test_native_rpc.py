@@ -367,33 +367,35 @@ def test_native_kill_and_revive():
 
     port = native.rpc_server_start(native_echo=True)
     assert port > 0
-    h = native.channel_open("127.0.0.1", port, connect_timeout_ms=2000,
-                            health_check_ms=50)
-    rc, body, _ = native.channel_call(h, "EchoService", "Echo", b"pre",
-                                      timeout_ms=3000)
-    assert rc == 0 and body == b"pre"
+    try:
+        h = native.channel_open("127.0.0.1", port, connect_timeout_ms=2000,
+                                health_check_ms=50)
+        rc, body, _ = native.channel_call(h, "EchoService", "Echo", b"pre",
+                                          timeout_ms=3000)
+        assert rc == 0 and body == b"pre"
 
-    native.rpc_server_stop()
-    # the failed socket must fail calls (not hang); reconnect attempts
-    # against a dead port must respect the connect timeout
-    rc, _, _ = native.channel_call(h, "EchoService", "Echo", b"mid",
-                                   timeout_ms=500)
-    assert rc != 0
+        native.rpc_server_stop()
+        # the failed socket must fail calls (not hang); reconnect attempts
+        # against a dead port must respect the connect timeout
+        rc, _, _ = native.channel_call(h, "EchoService", "Echo", b"mid",
+                                       timeout_ms=500)
+        assert rc != 0
 
-    # restart on the SAME port (stop->start cycle, server.h:426-441)
-    port2 = native.rpc_server_start(port=port, native_echo=True)
-    assert port2 == port
-    deadline = time.monotonic() + 10
-    rc = -1
-    while time.monotonic() < deadline:
-        rc, body, _ = native.channel_call(h, "EchoService", "Echo", b"post",
-                                          timeout_ms=1000)
-        if rc == 0:
-            break
-        time.sleep(0.05)
-    assert rc == 0 and body == b"post"
-    native.channel_close(h)
-    native.rpc_server_stop()
+        # restart on the SAME port (stop->start cycle, server.h:426-441)
+        port2 = native.rpc_server_start(port=port, native_echo=True)
+        assert port2 == port
+        deadline = time.monotonic() + 10
+        rc = -1
+        while time.monotonic() < deadline:
+            rc, body, _ = native.channel_call(h, "EchoService", "Echo",
+                                              b"post", timeout_ms=1000)
+            if rc == 0:
+                break
+            time.sleep(0.05)
+        assert rc == 0 and body == b"post"
+        native.channel_close(h)
+    finally:
+        native.rpc_server_stop()
 
 
 def test_native_retry_rides_over_restart():
@@ -401,21 +403,25 @@ def test_native_retry_rides_over_restart():
     SINGLE call with retries succeeds without any manual loop (the
     IssueRPC retry state machine role, controller.cpp:554-640)."""
     port = native.rpc_server_start(native_echo=True)
-    h = native.channel_open("127.0.0.1", port, connect_timeout_ms=2000)
-    rc, body, _ = native.channel_call(h, "EchoService", "Echo", b"a",
-                                      timeout_ms=3000)
-    assert rc == 0
-    native.rpc_server_stop()
-    port2 = native.rpc_server_start(port=port, native_echo=True)
-    assert port2 == port
-    # the first attempt fails on the dead socket; retries re-dial
-    rc, body, text = native.channel_call(h, "EchoService", "Echo",
-                                         b"retry-me", timeout_ms=10000,
-                                         max_retry=5)
-    assert rc == 0, (rc, text)
-    assert body == b"retry-me"
-    native.channel_close(h)
-    native.rpc_server_stop()
+    try:
+        h = native.channel_open("127.0.0.1", port, connect_timeout_ms=2000)
+        rc, body, _ = native.channel_call(h, "EchoService", "Echo", b"a",
+                                          timeout_ms=3000)
+        assert rc == 0
+        native.rpc_server_stop()
+        port2 = native.rpc_server_start(port=port, native_echo=True)
+        assert port2 == port
+        # the first attempt fails on the dead socket; retries re-dial
+        rc, body, text = native.channel_call(h, "EchoService", "Echo",
+                                             b"retry-me", timeout_ms=10000,
+                                             max_retry=5)
+        assert rc == 0, (rc, text)
+        assert body == b"retry-me"
+        native.channel_close(h)
+    finally:
+        # a server left running would fail every later native test in
+        # this process (one native-runtime server per process)
+        native.rpc_server_stop()
 
 
 def test_native_backup_request():

@@ -80,6 +80,11 @@ def server():
     sk.close()
     assert b"+OK\r\n" in got and b"$1\r\nv\r\n" in got
 
+    # the first exposition must read the counters this traffic moved, not
+    # a 0.25s-TTL snapshot the bvar sampler took before it
+    from brpc_tpu.bvar import native_vars
+
+    native_vars.settle_for_tests()
     yield srv, port
     srv.stop()
 
@@ -346,7 +351,6 @@ def test_native_stack_exits_clean():
     racing detached runtime threads."""
     script = (
         "import sys; sys.path.insert(0, '.')\n"
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "from brpc_tpu import rpc, native\n"
         "from brpc_tpu.rpc.proto import echo_pb2\n"
         "class E(rpc.Service):\n"

@@ -235,13 +235,20 @@ def test_rtmp_on_native_port():
         vconn, ply = _rtmp_connect(port)
         ply.send_command("createStream", 2.0, None)
         ply.send_command("play", 4.0, None, "ncam", stream_id=1)
-        ply.pump(want=1)
-        ply.inbox.clear()
-        pub.send_message(rtmp.MSG_VIDEO, 500, b"\x27\x01native-frame",
-                         stream_id=1)
         assert ply.pump_until(
-            lambda s: (rtmp.MSG_VIDEO, 500, b"\x27\x01native-frame")
-            in s.inbox), ply.inbox
+            lambda s: any(c[0] == "onStatus" and
+                          c[3]["code"] == "NetStream.Play.Start"
+                          for c in s.commands()))
+        ply.inbox.clear()
+        # live semantics: the server announces Play.Start before it
+        # subscribes the player, so a frame published in that instant
+        # may be missed — keep publishing until one arrives
+        frame = (rtmp.MSG_VIDEO, 500, b"\x27\x01native-frame")
+        for _ in range(10):
+            pub.send_message(frame[0], frame[1], frame[2], stream_id=1)
+            if ply.pump_until(lambda s: frame in s.inbox, timeout=1.0):
+                break
+        assert frame in ply.inbox, ply.inbox
         pconn.close()
         vconn.close()
     finally:

@@ -107,7 +107,6 @@ def test_two_process_relay_pull():
     via its RtmpClient, a player reads from B."""
     script = (
         "import sys; sys.path.insert(0, '.')\n"
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "from brpc_tpu import rpc\n"
         "from brpc_tpu.rpc import rtmp_protocol as rp\n"
         "svc = rp.RtmpService()\n"

@@ -23,6 +23,14 @@ lib = native.load()
 
 
 def _fresh_lane(arena_bytes):
+    # an in-process device server earlier in this worker leaves the
+    # tensor fabric's drain thread taking records: these tests take
+    # them themselves
+    from brpc_tpu.rpc import device_transport as dt
+
+    dt._fabric_stop.set()
+    if dt._fabric_thread is not None:
+        dt._fabric_thread.join(timeout=5)
     # a previous test's lane in this process must be fully shut down
     # (shutdown + unlink) before a new segment can replace it
     lib.nat_shm_lane_enable(0)
@@ -99,6 +107,12 @@ def test_arena_exhaustion_backpressure():
     lib.nat_req_free(h)
 
 
+# Host-load bound: a throughput ratio between record sizes on a shared
+# host. Alone, 64 KB and 1 MB records move 8-11 and 13-15 GB/s; beside the
+# tier-1 run's other five workers the 1 MB rate fell to 7.4-9.2 GB/s
+# while 64 KB read 13-14 GB/s, in 2 of ~30 full runs (PR 21), one of
+# them with the best of three interleaved windows per size.
+@pytest.mark.slow
 def test_record_size_sweep_monotone_throughput():
     """Per-record overhead must not dominate: pushing bigger records
     through the two-process lane yields more bytes/s (with slack for CI

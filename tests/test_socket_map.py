@@ -97,3 +97,50 @@ def test_fd_wait_and_connect(server):
     # not readable yet (no data): timeout path
     assert not bthread.fd_wait(s.fileno(), "r", timeout_s=0.05)
     s.close()
+
+
+def test_inline_writer_hands_off_after_its_own_message():
+    """A caller that becomes the socket's writer writes its own message
+    and hands whatever other threads queued behind it to KeepWrite
+    (socket.cpp StartWrite): draining their queue inline would hold the
+    caller for as long as they keep writing (a stream writer sharing the
+    connection held an RPC caller for a whole 2 s storm)."""
+    import threading
+
+    from brpc_tpu.butil.iobuf import IOBuf
+    from brpc_tpu.rpc.socket import Socket
+
+    a, b = pysocket.socketpair()
+    sock = Socket.address(Socket.create(fd=a))
+    writers = []
+
+    class Later(IOBuf):
+        def cut_into_socket(self, fd, *args):
+            writers.append(threading.get_ident())
+            return super().cut_into_socket(fd, *args)
+
+    later = Later()
+    later.append(b"y" * 100)
+
+    class Mine(IOBuf):
+        queued = False
+
+        def cut_into_socket(self, fd, *args):
+            if not self.queued:  # another producer queues behind us
+                self.queued = True
+                assert sock.write(later) == 0
+            return super().cut_into_socket(fd, *args)
+
+    mine = Mine()
+    mine.append(b"x" * 100)
+    try:
+        assert sock.write(mine) == 0
+        b.settimeout(5)
+        got = b""
+        while len(got) < 200:
+            got += b.recv(200 - len(got))
+        assert got == b"x" * 100 + b"y" * 100  # wire order kept
+        assert writers and writers[0] != threading.get_ident()
+    finally:
+        sock.set_failed()
+        b.close()

@@ -398,17 +398,22 @@ def test_native_lane_storm():
                threading.Thread(target=guard(http_loop, "http")),
                threading.Thread(target=guard(grpc_loop, "grpc")),
                threading.Thread(target=guard(stream_loop, "stream"))]
-    for t in threads:
-        t.start()
-    time.sleep(2.0)
-    stop.set()
-    for t in threads:
-        t.join(20)
-    assert not errors_seen, errors_seen[:3]
-    # every lane made real progress through the one port
-    assert progress["std"] > 10, progress
-    assert progress["http"] > 10, progress
-    assert progress["grpc"] > 10, progress
-    assert progress["stream"] > 2, progress
-    assert sink.nbytes > 0
-    srv.stop()
+    try:
+        for t in threads:
+            t.start()
+        time.sleep(2.0)
+        stop.set()
+        for t in threads:
+            t.join(20)
+        assert not errors_seen, errors_seen[:3]
+        # every lane made real progress through the one port
+        assert progress["std"] > 10, progress
+        assert progress["http"] > 10, progress
+        assert progress["grpc"] > 10, progress
+        assert progress["stream"] > 2, progress
+        assert sink.nbytes > 0
+    finally:
+        # a native-runtime server left running would fail every later
+        # native test in this process
+        stop.set()
+        srv.stop()

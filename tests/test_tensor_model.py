@@ -4,6 +4,8 @@ Mirrors the reference's strategy (SURVEY.md section 4): distributed behavior
 exercised with many in-process devices — SPMD output must match the
 single-device path bit-for-bit-ish (fp32 tolerance).
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -45,14 +47,12 @@ def test_ring_attention_matches_local():
     expect = local_attention(q, k, v, causal=True)
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
-    from brpc_tpu.jaxcompat import shard_map
-
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=True),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
         out_specs=P(None, "sp"),
-        check=False,
+        check_vma=False,
     )
     got = jax.jit(ring)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect), atol=2e-5)
@@ -108,3 +108,28 @@ def test_graft_entry_contract():
     out = jax.jit(fn)(*args)
     assert out.shape[0] == args[1].shape[0]
     ge.dryrun_multichip(8)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins when set (and nothing else is set);
+    otherwise the cache sits at a fixed path inside the checkout."""
+    from brpc_tpu import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.enable()
+        if env_dir is None:
+            assert got == compile_cache.CHECKOUT_CACHE_DIR
+            assert got == os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

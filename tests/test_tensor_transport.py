@@ -88,10 +88,55 @@ def test_push_pull_roundtrip(store_server):
     assert resp.ok
     stored = svc.get("w0")
     assert stored is not None
-    np.testing.assert_allclose(np.asarray(stored[0]), arrays[0])
+    # a host array pushed to the service lands on the server's device
+    import jax
+
+    assert isinstance(stored[0], jax.Array)
+    assert stored[0].devices() == {dt.default_block_pool().device}
+    np.testing.assert_array_equal(np.asarray(stored[0]), arrays[0])
     cntl2, pulled = client.pull("w0")
     assert not cntl2.failed(), cntl2.error_text
     np.testing.assert_allclose(np.asarray(pulled[0]), arrays[0])
+
+
+WIDE = [np.array([2**40 + 3, -(2**33), 2**31 + 1], dtype=np.int64),
+        np.array([1 + 2**-40, 1e300], dtype=np.float64),
+        np.arange(6, dtype=np.float32)]
+
+
+@pytest.mark.parametrize("lane", ["inproc", "wire"])
+def test_wide_dtypes_held_exactly(store_server, lane):
+    """int64 above 2^31 and float64 survive a push unchanged: the device
+    would narrow them (x64 off), so they stay host arrays while the
+    float32 beside them lands on the device."""
+    import jax
+
+    device = dt.default_block_pool().device
+    if lane == "inproc":
+        srv, svc = store_server
+        client = TensorClient(make_device_channel(str(srv.listen_endpoint)))
+        cntl, resp = client.push("wide", [a.copy() for a in WIDE])
+        assert not cntl.failed(), cntl.error_text
+        stored = svc.get("wide")
+        cntl2, pulled = client.pull("wide")
+        assert not cntl2.failed(), cntl2.error_text
+    else:
+        ep = dt.DeviceEndpoint()
+        ep.state = dt.FALLBACK_TCP
+        from brpc_tpu.butil.iobuf import IOBuf
+
+        meta, att = rpc_meta_pb2.RpcMeta(), IOBuf()
+        assert ep.prepare_send(WIDE, meta, att)
+        stored, _ = dt.receive_tensors(meta, att, device=device)
+        pulled = stored
+    for got, want in zip(stored[:2], WIDE[:2]):
+        assert isinstance(got, np.ndarray) and got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+    assert isinstance(stored[2], jax.Array)
+    assert stored[2].devices() == {device}
+    for got, want in zip(pulled, WIDE):
+        assert np.asarray(got).dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_pull_missing(store_server):
@@ -166,6 +211,13 @@ def test_block_pool_put_via_pool_roundtrip():
     arr8 = pool.put_via_pool(src8.copy(), np.uint8, (100,),
                              jax.devices()[0])
     np.testing.assert_array_equal(np.asarray(arr8), src8)
+    # a dtype the device would narrow (float64 without x64) is refused,
+    # never converted
+    src64 = np.linspace(0, 1, 10)
+    with pytest.raises(ValueError, match="narrowed"):
+        pool.put_via_pool(src64.view(np.uint8), np.float64, (10,),
+                          jax.devices()[0])
+    assert pool.stats() == before
     # oversized falls back to a plain device_put
     big = np.zeros(4 << 20, dtype=np.uint8)
     arr_big = pool.put_via_pool(big, np.uint8, (4 << 20,),
