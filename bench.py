@@ -14,9 +14,7 @@ JSON schema (one line on stdout):
       lanes) or call-begin -> completion (client lane)
   extra.device_lanes                   — device-transport GB/s rows
       (incl. shm_push_* over the descriptor-ring fabric,
-      read_arena_grow_GBps prefault-on-grow regression row, and .hops:
-      per-hop µs of the zero-copy path — arena-write / ring / consume /
-      device_put — so a fabric regression localizes to its hop)
+      and the read_arena_grow_GBps prefault-on-grow regression row)
   extra.scaling                        — with --cpus N: the per-core
       scaling curve {"1": qps, ..., "N": qps, "cpu_sets": ...} from
       taskset-pinned two-process echo runs; server and client runtimes

@@ -1890,57 +1890,6 @@ def device_lane_bench() -> dict:
     except Exception:
         pass
 
-    # per-hop breakdown of the fabric path (ISSUE 15 satellite): where a
-    # regression in the zero-copy pipeline lives — arena write (the ONE
-    # producer memcpy), ring latency (push -> take), consume (zero-copy
-    # lease -> np view), device_put (put_via_pool from the arena view).
-    # In-process: the hops are the same code the two-process lanes run.
-    try:
-        from brpc_tpu import native
-
-        lib = native.load()
-        lib.nat_shm_lane_enable(0)
-        if lib.nat_shm_lane_create(32 << 20) == 0 and \
-                lib.nat_shm_producer_attach(
-                    lib.nat_shm_lane_name()) >= 0:
-            src = np.random.randint(0, 255, 1 << 20, dtype=np.uint8)
-            hops = {"arena_write_us": [], "ring_us": [],
-                    "consume_us": [], "device_put_us": []}
-            from brpc_tpu.rpc.device_transport import \
-                default_block_pool
-
-            pool = default_block_pool()
-            for i in range(20):
-                t0 = time.perf_counter()
-                rc = native.fabric_push(src, i)
-                t1 = time.perf_counter()
-                if rc != 0:
-                    continue
-                lease = native.fabric_take(2000)
-                t2 = time.perf_counter()
-                if lease is None:
-                    continue
-                view = np.frombuffer(lease.view(), dtype=np.uint8)
-                t3 = time.perf_counter()
-                arr = pool.put_via_pool(view, np.uint8,
-                                        (view.size,))
-                t4 = time.perf_counter()
-                del arr
-                lease.release()
-                hops["arena_write_us"].append((t1 - t0) * 1e6)
-                hops["ring_us"].append((t2 - t1) * 1e6)
-                hops["consume_us"].append((t3 - t2) * 1e6)
-                hops["device_put_us"].append((t4 - t3) * 1e6)
-            if hops["arena_write_us"]:
-                import statistics
-
-                out["hops"] = {
-                    k: round(statistics.median(v), 1)
-                    for k, v in hops.items() if v}
-        lib.nat_shm_lane_enable(0)
-    except Exception:
-        pass
-
     # read-arena grow prefault (drive-by satellite): the growable
     # read-side allocator seam (install_read_arena) must not
     # reintroduce the first-touch fault cliff on grow (the r05

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 
 class Flag:
@@ -28,6 +28,7 @@ class Flag:
 
 _registry: Dict[str, Flag] = {}
 _lock = threading.Lock()
+_on_set: Dict[str, List[Callable[[Any], None]]] = {}
 
 
 def _define(name: str, default: Any, help_: str, type_: type,
@@ -103,7 +104,17 @@ def set_flag(name: str, value: Any) -> bool:
         if f.validator is not None and not f.validator(value):
             return False
         f.value = value
-        return True
+        hooks = list(_on_set.get(name, ()))
+    for fn in hooks:
+        fn(value)
+    return True
+
+
+def on_set(name: str, fn: Callable[[Any], None]):
+    """Calls fn(new_value) after every successful set_flag(name, ...), for
+    flags whose value must reach state outside this registry."""
+    with _lock:
+        _on_set.setdefault(name, []).append(fn)
 
 
 def all_flags() -> Dict[str, Flag]:

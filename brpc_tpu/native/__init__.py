@@ -274,6 +274,8 @@ def load() -> ctypes.CDLL:
         lib.nat_req_cid.restype = ctypes.c_int64
         lib.nat_req_aux.argtypes = [ctypes.c_void_p]
         lib.nat_req_aux.restype = ctypes.c_uint64
+        lib.nat_req_enqueue_ns.argtypes = [ctypes.c_void_p]
+        lib.nat_req_enqueue_ns.restype = ctypes.c_uint64
         lib.nat_req_compress.argtypes = [ctypes.c_void_p]
         lib.nat_req_compress.restype = ctypes.c_int32
         lib.nat_req_sock_id.argtypes = [ctypes.c_void_p]
@@ -796,20 +798,22 @@ def rpc_server_requests() -> int:
 def take_request(timeout_ms: int = 100):
     """Python lane: pull one item handed off by the native runtime.
     Returns (handle, kind, meta_bytes, payload, attachment, sock_id, seq,
-    f0, f1, aux) or None. kind 0 = parsed tpu_std request; 1 = raw
-    protocol bytes (seq orders chunks per socket); 2 = connection closed;
-    3 = native-parsed HTTP request (f0 = verb, f1 = uri, meta_bytes =
+    f0, f1, aux, enqueue_ns) or None. kind 0 = parsed tpu_std request;
+    1 = raw protocol bytes (seq orders chunks per socket); 2 = connection
+    closed; 3 = native-parsed HTTP request (f0 = verb, f1 = uri, meta_bytes =
     lowercased "key: value\\n" header lines, payload = body, seq = the
     connection-ordered response token for http_respond); 4 =
     native-parsed gRPC request (f1 = :path, payload = gRPC-framed body,
     seq = h2 stream id); 5 = streaming frame (aux = dest stream id,
     payload = frame body, seq orders frames per socket). aux is 0 except
-    for kind 5."""
+    for kind 5. enqueue_ns is the CLOCK_MONOTONIC time (time.monotonic_ns)
+    at which the request entered the queue, 0 for kind 2."""
     lib = load()
     h = lib.nat_take_request(timeout_ms)
     if not h:
         return None
     kind = lib.nat_req_kind(h)
+    enq = lib.nat_req_enqueue_ns(h)
     def field(which):
         n = ctypes.c_size_t(0)
         p = lib.nat_req_field(h, which, ctypes.byref(n))
@@ -817,14 +821,14 @@ def take_request(timeout_ms: int = 100):
     if kind in (3, 4):  # native-parsed HTTP / gRPC-over-h2
         return (h, kind, field(4), field(2), b"",
                 lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                field(0), field(1), 0)
+                field(0), field(1), 0, enq)
     if kind == 5:  # native-cut streaming frame: aux = dest stream id,
         # f0 = frame type (same contract as take_requests), cid = order
         return (h, kind, b"", field(2), b"", lib.nat_req_sock_id(h),
                 lib.nat_req_cid(h), lib.nat_req_compress(h), b"",
-                lib.nat_req_aux(h))
+                lib.nat_req_aux(h), enq)
     return (h, kind, field(4), field(2), field(3),
-            lib.nat_req_sock_id(h), lib.nat_req_cid(h), b"", b"", 0)
+            lib.nat_req_sock_id(h), lib.nat_req_cid(h), b"", b"", 0, enq)
 
 
 def take_requests(max_items: int = 16, timeout_ms: int = 100):
@@ -837,6 +841,7 @@ def take_requests(max_items: int = 16, timeout_ms: int = 100):
     for i in range(n):
         h = arr[i]
         kind = lib.nat_req_kind(h)
+        enq = lib.nat_req_enqueue_ns(h)
 
         def field(which, h=h):
             ln = ctypes.c_size_t(0)
@@ -846,7 +851,7 @@ def take_requests(max_items: int = 16, timeout_ms: int = 100):
         if kind in (3, 4):
             out.append((h, kind, field(4), field(2), b"",
                         lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                        field(0), field(1), 0))
+                        field(0), field(1), 0, enq))
         elif kind == 5:
             # frame type rides in the f0 slot (the zero-copy path below
             # hands the handle to a finalizer, so it can't be queried
@@ -865,15 +870,15 @@ def take_requests(max_items: int = 16, timeout_ms: int = 100):
                 payload = memoryview(cbuf).toreadonly()
                 out.append((None, kind, b"", payload, b"",
                             lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                            ftype, b"", lib.nat_req_aux(h)))
+                            ftype, b"", lib.nat_req_aux(h), enq))
                 continue
             out.append((h, kind, b"", field(2), b"",
                         lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                        ftype, b"", lib.nat_req_aux(h)))
+                        ftype, b"", lib.nat_req_aux(h), enq))
         else:
             out.append((h, kind, field(4), field(2), field(3),
                         lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                        b"", b"", 0))
+                        b"", b"", 0, enq))
     return out
 
 

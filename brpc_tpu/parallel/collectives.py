@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from brpc_tpu import rpcz
+
 
 def make_mesh(axis_sizes: dict, devices=None) -> Mesh:
     """Build a Mesh from {axis_name: size}; sizes must multiply to the
@@ -54,6 +56,8 @@ def _allreduce_fn(mesh: Mesh, axis: str, shape: Tuple[int, ...], dtype, op: str)
             return lax.pmean(x, axis)
         raise ValueError(f"unknown op {op}")
 
+    # the program is named after its operation (`jit_allreduce_add`)
+    local.__name__ = local.__qualname__ = f"allreduce_{op}"
     spec_in = P(axis)
     spec_out = P()  # replicated result
     return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=spec_in,
@@ -63,10 +67,12 @@ def _allreduce_fn(mesh: Mesh, axis: str, shape: Tuple[int, ...], dtype, op: str)
 def allreduce(mesh: Mesh, axis: str, x, op: str = "add"):
     """Every participant contributes its shard (dim 0 sharded over `axis`);
     all receive the reduction. The ParallelChannel+ResponseMerger fusion of
-    SURVEY.md section 2.12."""
-    x = jnp.asarray(x)
-    x = jax.device_put(x, NamedSharding(mesh, P(axis)))
-    return _allreduce_fn(mesh, axis, x.shape, x.dtype.name, op)(x)
+    SURVEY.md section 2.12. The host's launch is the
+    `collective.allreduce` phase span."""
+    with rpcz.phase("collective.allreduce"):
+        x = jnp.asarray(x)
+        x = jax.device_put(x, NamedSharding(mesh, P(axis)))
+        return _allreduce_fn(mesh, axis, x.shape, x.dtype.name, op)(x)
 
 
 @functools.lru_cache(maxsize=256)

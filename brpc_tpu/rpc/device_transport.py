@@ -32,7 +32,7 @@ import threading
 import uuid
 from typing import Dict, List, Optional, Tuple
 
-from brpc_tpu import bvar
+from brpc_tpu import bvar, rpcz
 from brpc_tpu.butil.iobuf import IOBuf
 
 # -- allocator tuning (the tcmalloc role) -----------------------------------
@@ -195,8 +195,9 @@ class DeviceBlockPool:
         target = device or self._device
         got = self.acquire(nbytes) if target == self._device else None
         if got is None:
-            return jax.device_put(
-                host_u8.view(np_dtype).reshape(shape), target)
+            with rpcz.phase("tensor.device_put"):
+                return jax.device_put(
+                    host_u8.view(np_dtype).reshape(shape), target)
         size_class, buf = got
         filled = None
         try:
@@ -204,17 +205,21 @@ class DeviceBlockPool:
             if padded != nbytes:
                 # np.empty + tail zero: one nbytes memcpy plus a small
                 # tail clear, not a full padded zero-fill + copy
-                staged = np.empty(padded, dtype=np.uint8)
-                staged[:nbytes] = host_u8
-                staged[nbytes:] = 0
+                with rpcz.phase("tensor.stage"):
+                    staged = np.empty(padded, dtype=np.uint8)
+                    staged[:nbytes] = host_u8
+                    staged[nbytes:] = 0
             else:
                 staged = host_u8
             fill, view = pool_programs()
-            filled = fill(buf, staged)
-            arr = view(filled, nbytes, np.dtype(np_dtype), tuple(shape))
+            with rpcz.phase("tensor.fill"):
+                filled = fill(buf, staged)
+            with rpcz.phase("tensor.view"):
+                arr = view(filled, nbytes, np.dtype(np_dtype), tuple(shape))
             # the pooled buffer may be re-donated the moment it returns
             # to the freelist: the slice/bitcast read must be complete
-            arr.block_until_ready()
+            with rpcz.phase("tensor.land_wait"):
+                arr.block_until_ready()
             return arr
         finally:
             if filled is not None:
@@ -236,22 +241,22 @@ def pool_programs():
     """The pool's two device programs, jitted once per process (jit keys
     them by shape): `fill(block, staged)` writes staged bytes over the
     head of a DONATED block, and `view(filled, nbytes, dtype, shape)`
-    slices and bitcasts the head into the typed array."""
+    slices and bitcasts the head into the typed array. Their traces name
+    them `jit_pool_fill` and `jit_pool_view`."""
     import jax
 
-    fill = jax.jit(
-        lambda block, staged: jax.lax.dynamic_update_slice(
-            block, staged, (0,)),
-        donate_argnums=(0,))
+    def pool_fill(block, staged):
+        return jax.lax.dynamic_update_slice(block, staged, (0,))
 
-    def view(filled, nbytes, dtype, shape):
+    def pool_view(filled, nbytes, dtype, shape):
         head = filled[:nbytes]
         if dtype.itemsize > 1:
             head = jax.lax.bitcast_convert_type(
                 head.reshape(-1, dtype.itemsize), dtype)
         return head.reshape(shape)
 
-    return fill, jax.jit(view, static_argnums=(1, 2, 3))
+    return (jax.jit(pool_fill, donate_argnums=(0,)),
+            jax.jit(pool_view, static_argnums=(1, 2, 3)))
 
 
 _default_pool: Optional[DeviceBlockPool] = None
@@ -584,7 +589,7 @@ def _fabric_claim(tag: int, timeout_s: float = 10.0):
     import time
 
     deadline = time.monotonic() + timeout_s
-    with _fabric_cv:
+    with rpcz.phase("tensor.claim"), _fabric_cv:
         while True:
             entry = _fabric_records.pop(tag, None)
             if entry is not None:
@@ -831,7 +836,7 @@ class DeviceEndpoint:
         offset) descriptor (no payload on the wire); otherwise ->
         FALLBACK_TCP wire bytes."""
         total = sum(int(a.nbytes) for a in arrays)
-        with self._window_cond:
+        with rpcz.phase("tensor.window_wait"), self._window_cond:
             import time
 
             deadline = time.monotonic() + timeout_s
@@ -914,14 +919,16 @@ class DeviceEndpoint:
                 import numpy as np
 
                 pos = offset
-                for a in arrays:
-                    n = int(a.nbytes)
-                    dst = np.frombuffer(arena.shm.buf, dtype=np.uint8,
-                                        count=n, offset=pos)
-                    # one device->host DMA straight into registered memory
-                    host = np.ascontiguousarray(np.asarray(a))
-                    dst[:] = host.reshape(-1).view(np.uint8)
-                    pos += n
+                with rpcz.phase("tensor.arena_write"):
+                    for a in arrays:
+                        n = int(a.nbytes)
+                        dst = np.frombuffer(arena.shm.buf, dtype=np.uint8,
+                                            count=n, offset=pos)
+                        # one device->host DMA straight into registered
+                        # memory
+                        host = np.ascontiguousarray(np.asarray(a))
+                        dst[:] = host.reshape(-1).view(np.uint8)
+                        pos += n
                 meta.tensors[0].sharding_spec = (
                     f"shm:{arena.name}:{offset}:{seq}")
                 _dev_shm.update(1)
@@ -965,13 +972,14 @@ class DeviceEndpoint:
             # right response is falling back to the shm-arena lane, not
             # stalling the send path (size the fabric to the consumer's
             # retention with BRPC_TPU_FABRIC_ARENA).
-            deadline = time.monotonic() + 0.25
-            while native.fabric_push(flat, base + i) != 0:
-                if time.monotonic() >= deadline:
-                    # stranded records (tags base..base+pushed-1) are
-                    # purged by the receiver's registry TTL
-                    return False
-                time.sleep(0.0005)
+            with rpcz.phase("tensor.arena_write"):
+                deadline = time.monotonic() + 0.25
+                while native.fabric_push(flat, base + i) != 0:
+                    if time.monotonic() >= deadline:
+                        # stranded records (tags base..base+pushed-1) are
+                        # purged by the receiver's registry TTL
+                        return False
+                    time.sleep(0.0005)
             pushed += 1
         meta.tensors[0].sharding_spec = (
             f"ring:{base}:{len(arrays)}:{seq}")
@@ -1144,10 +1152,12 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
                                       sharding=sharding)
                  for t in meta.tensors]
         try:
-            arrays = conn.pull(int(uid_s), specs)
+            with rpcz.phase("tensor.device_put"):
+                arrays = conn.pull(int(uid_s), specs)
             # the sender frees its buffers once our pull completes —
             # finish it before the caller ACKs (retention-until-ACK)
-            jax.block_until_ready(arrays)
+            with rpcz.phase("tensor.land_wait"):
+                jax.block_until_ready(arrays)
         except Exception:
             _xfer_evict(addr)  # redial next time (sender restarts)
             raise
@@ -1208,7 +1218,8 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
 
                 # the async H2D copies must finish before the spans are
                 # handed back to the producer's reclaim
-                jax.block_until_ready(arrays)
+                with rpcz.phase("tensor.land_wait"):
+                    jax.block_until_ready(arrays)
             for i, lease in enumerate(leases):
                 if i not in bound:
                     lease.release()
@@ -1226,11 +1237,13 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
 
             # arrays already on the device pass through as the SAME
             # object; host arrays the sender published land in HBM
-            arrays = [a if (isinstance(a, jax.Array)
-                            and a.devices() == {device})
-                      or _landing(device, a.dtype) is None
-                      else jax.device_put(a, device) for a in arrays]
-            jax.block_until_ready(arrays)
+            with rpcz.phase("tensor.device_put"):
+                arrays = [a if (isinstance(a, jax.Array)
+                                and a.devices() == {device})
+                          or _landing(device, a.dtype) is None
+                          else jax.device_put(a, device) for a in arrays]
+            with rpcz.phase("tensor.land_wait"):
+                jax.block_until_ready(arrays)
         return arrays, seq
     if parts[0] == "shm" and len(parts) == 4:
         arena = attach_arena(parts[1])
@@ -1265,7 +1278,8 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
             # the async H2D copies must finish before the caller ACKs —
             # the sender reuses the span after ACK (retention-until-ACK,
             # rdma_endpoint.h:214)
-            jax.block_until_ready(arrays)
+            with rpcz.phase("tensor.land_wait"):
+                jax.block_until_ready(arrays)
         return arrays, seq
     # wire path: materialize from attachment bytes
     import numpy as np

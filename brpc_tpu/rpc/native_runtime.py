@@ -13,6 +13,7 @@ native socket via a shim.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 from brpc_tpu import native
@@ -241,15 +242,11 @@ class NativeRuntimeMount:
             register_native_bvars()
         except Exception:
             pass
-        try:
-            import brpc_tpu.rpcz  # noqa: F401  (defines the rpcz flags)
-            from brpc_tpu.butil import flags as _flags
+        from brpc_tpu import rpcz
+        from brpc_tpu.butil import flags as _flags
 
-            if _flags.get_flag("enable_rpcz"):
-                native.stats_enable_spans(
-                    max(1, _flags.get_flag("rpcz_sample_every")))
-        except Exception:
-            pass
+        if _flags.get_flag("enable_rpcz"):
+            rpcz.sync_native_spans()
         # full protocol registry for the raw fallback lane: the native
         # port keeps the Python port's one-port-all-protocols capability
         protocols = list_server_protocols()
@@ -381,20 +378,28 @@ class NativeRuntimeMount:
 
     # -- the py lane --------------------------------------------------------
     def _worker(self):
-        from brpc_tpu.rpc.tpu_std_protocol import RpcMessage, process_request
-
         while not self._stopping:
             items = native.take_requests(16, 100)
             if not items:
                 continue
-            for item in items:
-                self._dispatch_one(item)
+            # nat_py_queue_wait_ns: each request's wait from its enqueue
+            # stamp to the start of its dispatch (behind the earlier items
+            # of its batch too), charged once per batch
+            waited = 0
+            try:
+                for item in items:
+                    if item[10]:
+                        waited += time.monotonic_ns() - item[10]
+                    self._dispatch_one(item)
+            finally:
+                if waited > 0:
+                    native.stats_counter_bump("nat_py_queue_wait_ns", waited)
 
     def _dispatch_one(self, item):
         from brpc_tpu.rpc.tpu_std_protocol import RpcMessage, process_request
 
         (handle, kind, meta_bytes, payload, attachment, sock_id, seq,
-         f0, f1, aux) = item
+         f0, f1, aux, _enqueue_ns) = item
         if kind == 5:  # native-cut streaming frame
             ftype = f0  # frame type rides in the tuple (zero-copy big
             # payloads hand their handle to a GC finalizer: handle=None)

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from brpc_tpu import rpcz
 from brpc_tpu.bthread import id as bthread_id
 from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.rpc import compress as compress_mod
@@ -247,29 +248,39 @@ def process_response(msg: RpcMessage):
 
 def send_rpc_response(sock, correlation_id: int, cntl: Controller,
                       response, attachment: IOBuf):
-    """SendRpcResponse analog (baidu_rpc_protocol.cpp:139)."""
-    # Handlers may have pre-filled tensors into the response meta.
-    meta = cntl._response_meta or rpc_meta_pb2.RpcMeta()
-    meta.correlation_id = correlation_id
-    meta.response.error_code = cntl.error_code_value
-    if cntl.error_code_value:
-        meta.response.error_text = cntl.error_text_value
-    if cntl._accepted_stream is not None:
-        meta.stream_id = cntl._accepted_stream.stream_id
-    payload = b""
-    if response is not None and not cntl.failed():
-        payload = (bytes(response) if isinstance(response, (bytes, bytearray))
-                   else response.SerializeToString())
-        payload = compress_mod.compress(payload, cntl.compress_type)
-    meta.compress_type = cntl.compress_type
-    frame = pack_frame(meta, payload, attachment)
-    sock.write(frame)
+    """SendRpcResponse analog (baidu_rpc_protocol.cpp:139); the
+    `rpc.respond` phase span."""
+    with rpcz.phase("rpc.respond"):
+        # Handlers may have pre-filled tensors into the response meta.
+        meta = cntl._response_meta or rpc_meta_pb2.RpcMeta()
+        meta.correlation_id = correlation_id
+        meta.response.error_code = cntl.error_code_value
+        if cntl.error_code_value:
+            meta.response.error_text = cntl.error_text_value
+        if cntl._accepted_stream is not None:
+            meta.stream_id = cntl._accepted_stream.stream_id
+        payload = b""
+        if response is not None and not cntl.failed():
+            payload = (bytes(response)
+                       if isinstance(response, (bytes, bytearray))
+                       else response.SerializeToString())
+            payload = compress_mod.compress(payload, cntl.compress_type)
+        meta.compress_type = cntl.compress_type
+        frame = pack_frame(meta, payload, attachment)
+        sock.write(frame)
     if cntl.close_connection_flag:
         sock.set_failed(errors.ECLOSE, "close_connection requested")
 
 
 def process_request(msg: RpcMessage):
-    """Server path (ProcessRpcRequest, baidu_rpc_protocol.cpp:314)."""
+    """Server path (ProcessRpcRequest, baidu_rpc_protocol.cpp:314), from
+    the request's parse to the response handed to the socket: the
+    `rpc.handle` phase span."""
+    with rpcz.phase("rpc.handle"):
+        return _process_request(msg)
+
+
+def _process_request(msg: RpcMessage):
     server = msg.arg
     meta = msg.meta
     cid = meta.correlation_id
@@ -342,8 +353,6 @@ def process_request(msg: RpcMessage):
 
     response = method_info.response_class()
     responded = [False]
-
-    from brpc_tpu import rpcz
 
     span = rpcz.start_server_span(
         f"{cntl.service_name}.{cntl.method_name}", meta, sock.remote_side)

@@ -6,11 +6,21 @@ parent under the enclosing server span via thread-local state (the
 tls_bls.rpcz_parent_span trick, span.h:76,116); trace/span ids propagate in
 the RpcMeta; spans are sampled into a bounded collector (the
 bvar::Collector role with its global sample budget, collector.h:40) and
-browsable at /rpcz. Annotate() adds free-text timeline entries.
+browsable at /rpcz. Annotate() adds free-text timeline entries. As in
+brpc, collection is off until the enable_rpcz flag is set.
+
+phase(name) is the other kind of span: a named phase inside a call (the
+fabric claim, the landing wait, an all-reduce launch) recorded on the JAX
+profiler's host plane, on the clock of the device's own events. It costs
+one check while no profiler trace runs, and records only while one does
+(/hotspots/tpu, or any jax.profiler trace).
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -18,7 +28,7 @@ from typing import Deque, List, Optional
 
 from brpc_tpu.butil import flags
 
-flags.define_bool("enable_rpcz", True, "collect rpcz spans")
+flags.define_bool("enable_rpcz", False, "collect rpcz spans")
 flags.define_int("rpcz_max_spans", 4096,
                  "bounded span store (collector budget analog)")
 flags.define_int("rpcz_sample_every", 1,
@@ -82,6 +92,55 @@ class Span:
             offset_us = (ts - self.start_time) * 1e6
             lines.append(f"    +{offset_us:.0f}us {text}")
         return "\n".join(lines)
+
+
+# -- phase spans on the profiler's clock -------------------------------------
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation once it is loaded
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _annotation_class():
+    """jax.profiler.TraceAnnotation, or None while the process has not
+    loaded jax.profiler: it then has no trace to record into, and a span
+    must not pay for importing JAX."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is not None:  # (no attribute yet while it is importing)
+            _TraceAnnotation = getattr(prof, "TraceAnnotation", None)
+    return _TraceAnnotation
+
+
+def phase(name: str):
+    """`with phase("tensor.fill"): ...` records a span named `name` in the
+    running profiler trace; with no trace running it returns one shared
+    no-op context and builds nothing."""
+    ta = _TraceAnnotation or _annotation_class()
+    if ta is None or not ta.is_enabled():
+        return _NO_SPAN
+    return ta(name)
+
+
+_gc_span = [None]
+
+
+def _gc_phase(stage: str, info: dict):
+    """gc.callbacks hook: each collector pass is a `py.gc` span, with its
+    generation, while a trace runs."""
+    if stage == "start":
+        ta = _TraceAnnotation or _annotation_class()
+        if ta is not None and ta.is_enabled():
+            span = ta("py.gc", generation=info.get("generation", -1))
+            span.__enter__()
+            _gc_span[0] = span
+    else:
+        span, _gc_span[0] = _gc_span[0], None
+        if span is not None:
+            span.__exit__(None, None, None)
+
+
+gc.callbacks.append(_gc_phase)
 
 
 # -- thread-local parenting (tls_bls analog) --------------------------------
@@ -323,6 +382,23 @@ def _submit(span: Span):
             db.append(span)
     except Exception:
         pass  # disk trouble must never fail the RPC path
+
+
+def sync_native_spans(_value=None):
+    """Native span sampling follows enable_rpcz and rpcz_sample_every:
+    called when either flag is set and when a native server starts. A
+    native core that is not loaded yet has nothing to switch."""
+    native = sys.modules.get("brpc_tpu.native")
+    if native is None or native._lib is None:
+        return
+    every = 0
+    if flags.get_flag("enable_rpcz"):
+        every = max(1, flags.get_flag("rpcz_sample_every"))
+    native.stats_enable_spans(every)
+
+
+flags.on_set("enable_rpcz", sync_native_spans)
+flags.on_set("rpcz_sample_every", sync_native_spans)
 
 
 def drain_native_spans() -> int:

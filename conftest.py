@@ -22,3 +22,18 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # first `import jax` inside a test competes with that test's busy server
 # threads for the interpreter lock and can take tens of seconds.
 import jax  # noqa: E402,F401
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def rpcz_on():
+    """rpcz collection on for a module that reads spans: it is off by
+    default, as brpc's enable_rpcz is. Setting the flag also switches the
+    native runtime's span sampling."""
+    from brpc_tpu import rpcz  # noqa: F401  (defines the flag)
+    from brpc_tpu.butil import flags
+
+    flags.set_flag("enable_rpcz", True)
+    yield
+    flags.set_flag("enable_rpcz", False)

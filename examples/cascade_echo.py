@@ -11,6 +11,7 @@ import sys
 sys.path.insert(0, ".")
 
 from brpc_tpu import rpc, rpcz  # noqa: E402
+from brpc_tpu.butil import flags  # noqa: E402
 from brpc_tpu.rpc.proto import echo_pb2  # noqa: E402
 
 
@@ -38,6 +39,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--depth", type=int, default=3)
     args = ap.parse_args()
+    flags.set_flag("enable_rpcz", True)  # collection is off by default
 
     servers = []
     next_ch = None

@@ -294,6 +294,7 @@ int main() {
       NAT_SYM(nat_req_field),
       NAT_SYM(nat_req_cid),
       NAT_SYM(nat_req_aux),
+      NAT_SYM(nat_req_enqueue_ns),
       NAT_SYM(nat_req_compress),
       NAT_SYM(nat_req_sock_id),
       NAT_SYM(nat_req_free),

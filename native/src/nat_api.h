@@ -92,6 +92,7 @@ int32_t nat_req_kind(void* h);
 const char* nat_req_field(void* h, int which, size_t* len);
 int64_t nat_req_cid(void* h);
 uint64_t nat_req_aux(void* h);
+uint64_t nat_req_enqueue_ns(void* h);
 int32_t nat_req_compress(void* h);
 uint64_t nat_req_sock_id(void* h);
 void nat_req_free(void* h);

@@ -622,7 +622,8 @@ struct PyRequest {
   uint64_t trace_id = 0;
   uint64_t parent_span_id = 0;
   // overload accounting (nat_overload.cpp): enqueue_ns stamped when a
-  // limiter/deadline is configured; admitted = this request holds one
+  // limiter/deadline is configured, and by enqueue_py on every request
+  // bound for the Python lane (nat_py_queue_wait_ns); admitted = this request holds one
   // in-flight slot, released exactly once (dtor, or transferred to the
   // shm in-flight table when the request rides the worker rings).
   // admit_ok mirrors AutoLimiter.on_response's error filter: responders
@@ -770,6 +771,9 @@ class NatServer {
     // worker-process lane first (kinds 3/4 when enabled): usercode runs
     // across N interpreters instead of behind this process's GIL
     if ((r->kind == 3 || r->kind == 4) && shm_lane_offer(r)) return;
+    // the Python lane charges nat_py_queue_wait_ns from this stamp (one
+    // admission control may already have taken at the gate above)
+    if (r->kind != 2 && r->enqueue_ns == 0) r->enqueue_ns = nat_now_ns();
     {
       std::lock_guard g(py_mu);
       py_q.push_back(r);

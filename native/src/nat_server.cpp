@@ -577,6 +577,10 @@ int nat_rpc_server_redis(int mode) {
 
 int32_t nat_req_kind(void* h) { return ((PyRequest*)h)->kind; }
 
+// CLOCK_MONOTONIC ns at which the request entered the Python lane's queue
+// (0 for a connection-closed notice)
+uint64_t nat_req_enqueue_ns(void* h) { return ((PyRequest*)h)->enqueue_ns; }
+
 uint64_t nat_rpc_server_requests() {
   std::lock_guard g(g_rt_mu);
   return g_rpc_server

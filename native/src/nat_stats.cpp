@@ -132,6 +132,7 @@ static const char* kCounterNames[NS_COUNTER_COUNT] = {
     "nat_autoscale_grows",
     "nat_autoscale_shrinks",
     "nat_autoscale_blocked",
+    "nat_py_queue_wait_ns",
 };
 
 static const char* kLaneNames[NL_LANE_COUNT] = {

@@ -102,6 +102,9 @@ enum NatCounterId : int {
   NS_AUTOSCALE_SHRINKS,     // autoscaler shrink actions applied
   NS_AUTOSCALE_BLOCKED,     // autoscaler actions withheld (SLO burning,
                             // min/max bound, members still draining)
+  NS_PY_QUEUE_WAIT_NS,      // summed ns py-lane requests waited from
+                            // enqueue_py until their Python dispatch began
+                            // (charged from Python, once per taken batch)
   NS_COUNTER_COUNT,
 };
 

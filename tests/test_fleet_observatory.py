@@ -26,6 +26,9 @@ import pytest
 from brpc_tpu.fleet import hist
 from brpc_tpu.fleet.slo import SloEngine, SloObjective
 
+# rpcz collection is off by default; this module reads spans
+pytestmark = pytest.mark.usefixtures("rpcz_on")
+
 native = pytest.importorskip("brpc_tpu.native")
 if not native.available():
     pytest.skip("native toolchain unavailable", allow_module_level=True)

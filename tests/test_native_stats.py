@@ -18,6 +18,9 @@ import pytest
 from brpc_tpu import rpc
 from brpc_tpu.rpc.proto import echo_pb2
 
+# rpcz collection is off by default; this module reads spans
+pytestmark = pytest.mark.usefixtures("rpcz_on")
+
 native = pytest.importorskip("brpc_tpu.native")
 if not native.available():
     pytest.skip("native toolchain unavailable", allow_module_level=True)

@@ -12,6 +12,9 @@ from brpc_tpu import rpc, rpcz
 from brpc_tpu.butil import flags as flags_mod
 from brpc_tpu.rpc.proto import echo_pb2
 
+# rpcz collection is off by default; this module reads spans
+pytestmark = pytest.mark.usefixtures("rpcz_on")
+
 
 class EchoService(rpc.Service):
     @rpc.rpc_method(echo_pb2.EchoRequest, echo_pb2.EchoResponse)

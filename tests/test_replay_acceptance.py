@@ -24,6 +24,9 @@ if not native.available():
 
 from brpc_tpu.butil.recordio import RecordReader  # noqa: E402
 
+# rpcz collection is off by default; this module reads spans
+pytestmark = pytest.mark.usefixtures("rpcz_on")
+
 N_STD = 25
 N_HTTP = 10
 TRACE_BASE = 0xACE0_0000

@@ -32,6 +32,9 @@ if not native.available():
 
 from brpc_tpu.rpc.proto import echo_pb2  # noqa: E402
 
+# rpcz collection is off by default; this module reads spans
+pytestmark = pytest.mark.usefixtures("rpcz_on")
+
 
 class EchoService(rpc.Service):
     @rpc.rpc_method(echo_pb2.EchoRequest, echo_pb2.EchoResponse)

@@ -10,6 +10,9 @@ import pytest
 from brpc_tpu import rpc, rpcz
 from brpc_tpu.rpc.proto import echo_pb2
 
+# rpcz collection is off by default; this module reads spans
+pytestmark = pytest.mark.usefixtures("rpcz_on")
+
 
 class FrontService(rpc.Service):
     """Calls a backend inside its handler — the cascade shape that must
