@@ -112,58 +112,96 @@ def local_device_info(arm_fabric: bool = False) -> dict:
 
 _pool_acquired = bvar.Adder("device_block_pool_acquired")
 _pool_released = bvar.Adder("device_block_pool_released")
+# acquisitions by block word width: which widths the traffic engages
+_pool_acquired_by_word = {
+    word: bvar.Adder(f"device_block_pool_acquired_u{8 * width}")
+    for width, word in ((1, "uint8"), (2, "uint16"), (4, "uint32"))}
+
+
+def _block_word(np_dtype) -> str:
+    """The unsigned word a pooled block holds for a payload of `np_dtype`:
+    as wide as one element (up to 4 bytes), so the view is a same-width
+    bitcast. Wider dtypes keep byte blocks."""
+    import numpy as np
+
+    return {2: "uint16", 4: "uint32"}.get(np.dtype(np_dtype).itemsize,
+                                          "uint8")
 
 
 class DeviceBlockPool:
-    """Pre-allocated HBM byte-buffers by size class — the role of the
-    reference's registered-memory pool (block_pool.h:29-94: arenas carved
-    into 8KB/64KB/2MB blocks that ALL transfer traffic flows through).
+    """Pre-allocated HBM blocks by size class and word width — the role
+    of the reference's registered-memory pool (block_pool.h:29-94: arenas
+    carved into 8KB/64KB/2MB blocks that ALL transfer traffic flows
+    through).
 
     The jax-idiomatic rendition: incoming transfer bytes are written into
     a pooled buffer with a DONATING jitted update, so the pooled HBM is
     genuinely the memory the bytes land in (no per-transfer allocation),
-    then bitcast/sliced into the typed array handed to the application.
+    then sliced and bitcast into the typed array handed to the
+    application. A block holds unsigned words as wide as the payload's
+    elements (uint8, uint16 or uint32; `_block_word`), so that bitcast
+    reinterprets whole elements and never repacks bytes; size classes are
+    in bytes whatever the word. Byte blocks are allocated up front, a
+    wider word's blocks the first time that width lands.
     acquire()/release() remain available for raw leases."""
 
     SIZE_CLASSES = (8 << 10, 64 << 10, 2 << 20)  # block_pool's classes
 
     def __init__(self, blocks_per_class: int = 8, device=None):
         import jax
-        import jax.numpy as jnp
 
         self._device = device or jax.devices()[0]
-        self._free: Dict[int, List] = {}
+        self._blocks_per_class = blocks_per_class
+        self._free: Dict[Tuple[int, str], List] = {}
         self._lock = threading.Lock()
-        for size in self.SIZE_CLASSES:
-            buffers = []
-            for _ in range(blocks_per_class):
-                buf = jax.device_put(
-                    jnp.zeros((size,), dtype=jnp.uint8), self._device
-                )
-                buffers.append(buf)
-            self._free[size] = buffers
+        with self._lock:
+            self._allocate_word("uint8")
 
     @property
     def device(self):
         """The device whose HBM the pool's blocks live in."""
         return self._device
 
-    def acquire(self, nbytes: int):
-        """Returns (size_class, buffer) or None if exhausted/oversized."""
+    def _new_block(self, size_class: int, word: str):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        return jax.device_put(
+            jnp.zeros((size_class // np.dtype(word).itemsize,), word),
+            self._device)
+
+    def _allocate_word(self, word: str):
+        # caller holds self._lock
+        if (self.SIZE_CLASSES[0], word) in self._free:
+            return
+        for size in self.SIZE_CLASSES:
+            self._free[(size, word)] = [
+                self._new_block(size, word)
+                for _ in range(self._blocks_per_class)]
+
+    def acquire(self, nbytes: int, word: str = "uint8"):
+        """Returns (size_class, buffer) — a block of `word` elements
+        holding at least `nbytes` — or None if exhausted/oversized."""
         with self._lock:
+            self._allocate_word(word)
             for size in self.SIZE_CLASSES:
-                if nbytes <= size and self._free[size]:
+                free = self._free[(size, word)]
+                if nbytes <= size and free:
                     _pool_acquired.update(1)
-                    return size, self._free[size].pop()
+                    _pool_acquired_by_word[word].update(1)
+                    return size, free.pop()
         return None
 
     def release(self, size_class: int, buf):
+        key = (size_class, str(buf.dtype))
         with self._lock:
-            if size_class in self._free:
-                self._free[size_class].append(buf)
+            if key in self._free:
+                self._free[key].append(buf)
                 _pool_released.update(1)
 
-    def stats(self) -> Dict[int, int]:
+    def stats(self) -> Dict[Tuple[int, str], int]:
+        """Free blocks per (size class in bytes, word dtype name)."""
         with self._lock:
             return {k: len(v) for k, v in self._free.items()}
 
@@ -179,12 +217,12 @@ class DeviceBlockPool:
 
     def put_via_pool(self, host_u8, np_dtype, shape, device=None):
         """Host->device put of raw bytes THROUGH pooled memory: the bytes
-        land in a pooled buffer (donated update — same HBM each time),
-        then a device-side slice+bitcast produces the typed array. Falls
-        back to a plain device_put when the pool is exhausted, the
-        payload is oversized, or a different target device is asked for.
-        Returns a jax.Array of `np_dtype`/`shape`; a dtype the device
-        would narrow (see `device_holds`) is refused."""
+        land in a pooled block of `np_dtype`'s word width (donated update
+        — same HBM each time), then a device-side slice+bitcast produces
+        the typed array. Falls back to a plain device_put when the pool
+        is exhausted, the payload is oversized, or a different target
+        device is asked for. Returns a jax.Array of `np_dtype`/`shape`; a
+        dtype the device would narrow (see `device_holds`) is refused."""
         import jax
         import numpy as np
 
@@ -193,7 +231,9 @@ class DeviceBlockPool:
                              f"narrowed on the device (x64 is off)")
         nbytes = int(host_u8.size)
         target = device or self._device
-        got = self.acquire(nbytes) if target == self._device else None
+        word = _block_word(np_dtype)
+        got = (self.acquire(nbytes, word) if target == self._device
+               else None)
         if got is None:
             with rpcz.phase("tensor.device_put"):
                 return jax.device_put(
@@ -213,7 +253,9 @@ class DeviceBlockPool:
                 staged = host_u8
             fill, view = pool_programs()
             with rpcz.phase("tensor.fill"):
-                filled = fill(buf, staged)
+                # a reinterpretation, no copy: nbytes is a whole number
+                # of elements and the padded length a multiple of 4096
+                filled = fill(buf, staged.view(word))
             with rpcz.phase("tensor.view"):
                 arr = view(filled, nbytes, np.dtype(np_dtype), tuple(shape))
             # the pooled buffer may be re-donated the moment it returns
@@ -229,19 +271,17 @@ class DeviceBlockPool:
             else:
                 # the fill failed mid-donation: buf may be dead — refill
                 # the class with a fresh buffer instead of a poisoned one
-                import jax.numpy as jnp
-
-                self.release(size_class, jax.device_put(
-                    jnp.zeros((size_class,), dtype=jnp.uint8),
-                    self._device))
+                self.release(size_class, self._new_block(size_class, word))
 
 
 @functools.lru_cache(maxsize=None)
 def pool_programs():
     """The pool's two device programs, jitted once per process (jit keys
-    them by shape): `fill(block, staged)` writes staged bytes over the
+    them by shape): `fill(block, staged)` writes staged words over the
     head of a DONATED block, and `view(filled, nbytes, dtype, shape)`
-    slices and bitcasts the head into the typed array. Their traces name
+    slices the head's `nbytes` and bitcasts them into the typed array —
+    elementwise when the block's word is as wide as `dtype`, else by
+    grouping bytes (a byte block under a wider dtype). Their traces name
     them `jit_pool_fill` and `jit_pool_view`."""
     import jax
 
@@ -249,10 +289,13 @@ def pool_programs():
         return jax.lax.dynamic_update_slice(block, staged, (0,))
 
     def pool_view(filled, nbytes, dtype, shape):
-        head = filled[:nbytes]
-        if dtype.itemsize > 1:
+        width = filled.dtype.itemsize
+        head = filled[:nbytes // width]
+        if dtype.itemsize == width:
+            head = head.view(dtype)
+        else:
             head = jax.lax.bitcast_convert_type(
-                head.reshape(-1, dtype.itemsize), dtype)
+                head.reshape(-1, dtype.itemsize // width), dtype)
         return head.reshape(shape)
 
     return (jax.jit(pool_fill, donate_argnums=(0,)),

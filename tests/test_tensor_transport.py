@@ -6,7 +6,7 @@ zero-copy same-process path, retention-until-ACK.
 import numpy as np
 import pytest
 
-from brpc_tpu import rpc
+from brpc_tpu import bvar, rpc
 from brpc_tpu.rpc import device_transport as dt
 from brpc_tpu.rpc.proto import rpc_meta_pb2
 from brpc_tpu.rpc.tensor_service import (
@@ -40,9 +40,10 @@ def test_block_pool_acquire_release():
     assert got is not None
     size, buf = got
     assert size == 64 << 10
-    assert pool.stats()[size] == 1
+    assert buf.dtype == np.uint8
+    assert pool.stats()[(size, "uint8")] == 1
     pool.release(size, buf)
-    assert pool.stats()[size] == 2
+    assert pool.stats()[(size, "uint8")] == 2
     assert pool.acquire(10 << 20) is None  # above the largest class
 
 
@@ -192,34 +193,74 @@ def test_device_jax_array_roundtrip(store_server):
     np.testing.assert_allclose(np.asarray(pulled[0]), np.asarray(arr))
 
 
-def test_block_pool_put_via_pool_roundtrip():
-    """Transfer bytes must land in pooled HBM (donating fill) and come
-    back out as the right typed array; pool counters show the traffic."""
+# payload dtype -> the word its pooled block holds
+_WORDS = {"float32": "uint32", "int32": "uint32", "bfloat16": "uint16",
+          "float16": "uint16", "int8": "uint8", "uint8": "uint8",
+          "bool": "uint8"}
+
+
+@pytest.mark.parametrize("payload", ["exact", "padded", "spilled"])
+@pytest.mark.parametrize("dtype", list(_WORDS))
+def test_block_pool_put_via_pool_roundtrip(dtype, payload):
+    """Transfer bytes must land in a pooled block of the dtype's word
+    width (donating fill) and come back out as the right typed array,
+    bit for bit; every block goes home and the width's counter moves.
+    `exact` fills the 8 KiB class, `padded` stages 6000 bytes up to it,
+    `spilled` finds the 8 KiB class busy and lands in the 64 KiB one."""
+    import jax
+
+    word = _WORDS[dtype]
+    np_dtype = dt._np_dtype(dtype)
+    pool = dt.DeviceBlockPool(blocks_per_class=2)
+    nbytes = 6000 if payload == "padded" else 8 << 10
+    rng = np.random.default_rng(nbytes)
+    raw = rng.integers(0, 256, nbytes, dtype=np.uint8)
+    if np_dtype == np.bool_:
+        raw &= 1
+    src = raw.view(np_dtype).reshape(2, -1)
+    held = []
+    if payload == "spilled":
+        held = [pool.acquire(nbytes, word) for _ in range(2)]
+        assert all(size == 8 << 10 for size, _ in held)
+    counter = bvar.find_exposed(f"device_block_pool_acquired_u"
+                                f"{8 * np.dtype(word).itemsize}")
+    count0, total0 = counter.get_value(), dt._pool_acquired.get_value()
+    arr = pool.put_via_pool(raw.copy(), np_dtype, src.shape,
+                            jax.devices()[0])
+    assert arr.dtype == np_dtype and arr.shape == src.shape
+    assert np.asarray(arr).tobytes() == src.tobytes()
+    assert counter.get_value() == count0 + 1
+    assert dt._pool_acquired.get_value() == total0 + 1
+    for size, buf in held:
+        pool.release(size, buf)
+    # every block is back home after the put, in every width allocated
+    assert pool.stats() == {(size, w): 2
+                            for size in dt.DeviceBlockPool.SIZE_CLASSES
+                            for w in {"uint8", word}}
+
+
+def test_block_pool_put_via_pool_refuses_narrowed():
+    """A dtype the device would narrow (float64 without x64) is refused,
+    never converted, and takes no block."""
     import jax
 
     pool = dt.DeviceBlockPool(blocks_per_class=2)
-    src = np.arange(640, dtype=np.float32).reshape(16, 40)
-    raw = np.frombuffer(src.tobytes(), dtype=np.uint8)
     before = pool.stats()
-    arr = pool.put_via_pool(raw, np.float32, (16, 40),
-                            jax.devices()[0])
-    np.testing.assert_array_equal(np.asarray(arr), src)
-    # every block is back home after the put
-    assert pool.stats() == before
-    # int8 path (itemsize 1, no bitcast)
-    src8 = np.arange(100, dtype=np.uint8)
-    arr8 = pool.put_via_pool(src8.copy(), np.uint8, (100,),
-                             jax.devices()[0])
-    np.testing.assert_array_equal(np.asarray(arr8), src8)
-    # a dtype the device would narrow (float64 without x64) is refused,
-    # never converted
     src64 = np.linspace(0, 1, 10)
     with pytest.raises(ValueError, match="narrowed"):
         pool.put_via_pool(src64.view(np.uint8), np.float64, (10,),
                           jax.devices()[0])
     assert pool.stats() == before
-    # oversized falls back to a plain device_put
+
+
+def test_block_pool_put_via_pool_oversized_falls_back():
+    """Above the largest class a put is a plain device_put."""
+    import jax
+
+    pool = dt.DeviceBlockPool(blocks_per_class=2)
+    before = pool.stats()
     big = np.zeros(4 << 20, dtype=np.uint8)
     arr_big = pool.put_via_pool(big, np.uint8, (4 << 20,),
                                 jax.devices()[0])
     assert arr_big.shape == (4 << 20,)
+    assert pool.stats() == before

@@ -64,6 +64,36 @@ def test_block_pool_programs_compile(one_chip, size_class):
     _fits_one_chip(compiled)
 
 
+@pytest.mark.parametrize("word,dtype", [("uint16", "bfloat16"),
+                                        ("uint32", "float32")])
+@pytest.mark.parametrize("size_class", [8 << 10, 64 << 10, 2 << 20])
+def test_block_pool_word_programs_compile(one_chip, size_class, word,
+                                          dtype):
+    """On a block of the payload's word width the view is one elementwise
+    bitcast: no temporaries, and none of the byte-block view's padded
+    repack (a rank-2 reshape and a shift-and-reduce of its rows)."""
+    import re
+
+    import jax
+
+    from brpc_tpu.rpc import device_transport as dt
+
+    assert dt._block_word(dt._np_dtype(dtype)) == word
+    fill, view = dt.pool_programs()
+    block = jax.ShapeDtypeStruct((size_class // np.dtype(word).itemsize,),
+                                 np.dtype(word), sharding=one_chip)
+    compiled = fill.lower(block, block).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    np_dtype = dt._np_dtype(dtype)
+    compiled = view.lower(block, size_class, np_dtype,
+                          (size_class // np_dtype.itemsize,)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    hlo = compiled.as_text()
+    assert "bitcast-convert" in hlo
+    assert "reduce" not in hlo
+    assert not re.search(r"\[\d+,\d+\]\S*\s+reshape\(", hlo)
+
+
 def test_flagship_train_step_compiles(topo, one_chip, monkeypatch):
     """The flagship step at its bench width (d2048), cut to 2 layers:
     the layer scan's body is the same at any depth."""
