@@ -1,0 +1,8 @@
+"""Host time of one table batch: the program's `table.batch` spans (the
+consumer's time for a batch: dedupe, staging, dispatch, wait, copy and
+the answers) in the traced window, over their count, in µs."""
+import table_spans
+
+
+def read(data):
+    return table_spans.per_span_us(data, "table.batch")

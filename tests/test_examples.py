@@ -38,6 +38,7 @@ EXAMPLES = [
     ("examples/usercode_workers.py", []),
     ("examples/rtmp_relay.py", []),
     ("examples/fanout_swarm.py", ["--backends", "6", "--seconds", "2"]),
+    ("examples/table_kv.py", ["--records", "512"]),
 ]
 
 

@@ -133,3 +133,21 @@ def test_allreduce_64mb_compiles_to_all_reduce(topo):
         mesh, "x", shape, "float32", "add").lower(x).compile()
     assert "all-reduce" in compiled.as_text()
     _fits_one_chip(compiled)
+
+
+def test_table_batch_program_updates_in_place(one_chip):
+    """TableService's batch program at the `kv.ycsb_b` cell's shape
+    (u32[8388608, 250], 8.6 GB with lanes padded to 256) and its largest
+    bucket: the table is aliased input to output, so the in-place update
+    never holds a second copy, and the scatter and gather need under
+    64 MiB of temporaries."""
+    from brpc_tpu.rpc import table_service as ts
+
+    records, words, field_words = 8388608, 250, 25
+    compiled = ts.batch_program().lower(*ts.batch_arg_shapes(
+        (records, words), field_words, ts.BUCKETS[-1], one_chip)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= records * words * 4
+    assert "input_output_alias={ {0}: (0, {}" in compiled.as_text()
+    assert m.temp_size_in_bytes < 64 << 20
+    _fits_one_chip(compiled)
