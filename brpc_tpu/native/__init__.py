@@ -291,6 +291,10 @@ def load() -> ctypes.CDLL:
         lib.nat_sock_write.argtypes = [
             ctypes.c_uint64, ctypes.c_char_p, ctypes.c_size_t]
         lib.nat_sock_write.restype = ctypes.c_int
+        lib.nat_sock_write_batch.argtypes = [
+            ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64),
+            ctypes.POINTER(ctypes.c_size_t), ctypes.c_int]
+        lib.nat_sock_write_batch.restype = ctypes.c_int
         lib.nat_sock_set_failed.argtypes = [ctypes.c_uint64]
         lib.nat_sock_set_failed.restype = ctypes.c_int
         lib.nat_respond.argtypes = [
@@ -899,6 +903,19 @@ def req_free(handle):
 
 def sock_write(sock_id: int, data: bytes) -> int:
     return load().nat_sock_write(sock_id, data, len(data))
+
+
+def sock_write_batch(items) -> int:
+    """Writes (sock_id, frame) pairs in one crossing: each connection gets
+    its frames in order, in one write; a connection that is gone is
+    skipped. Returns the frames written."""
+    n = len(items)
+    if not n:
+        return 0
+    ids = (ctypes.c_uint64 * n)(*(sock_id for sock_id, _ in items))
+    lens = (ctypes.c_size_t * n)(*(len(data) for _, data in items))
+    return load().nat_sock_write_batch(
+        b"".join(data for _, data in items), ids, lens, n)
 
 
 def sock_set_failed(sock_id: int) -> int:

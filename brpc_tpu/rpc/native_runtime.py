@@ -9,9 +9,14 @@ Python threads, never on fiber stacks — and the full Python server path
 (`process_request`: auth, interceptor, MethodStatus, rpcz spans,
 compression) executes unchanged, writing its response back through the
 native socket via a shim.
+
+A thread that answers many requests at once (a batching consumer) opens
+`coalesced_writes()` around its answers: the shim's writes then gather on
+the thread and leave in one native crossing when the scope closes.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Optional
@@ -20,6 +25,39 @@ from brpc_tpu import native
 from brpc_tpu.butil.endpoint import EndPoint
 from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.rpc.proto import rpc_meta_pb2
+
+
+class _WriteScope(threading.local):
+    frames = None  # (sock_id, bytes) pairs while a scope is open
+
+
+_scope = _WriteScope()
+
+
+def _flush_writes():
+    frames = _scope.frames
+    if frames:
+        native.sock_write_batch(frames)
+        frames.clear()
+
+
+@contextlib.contextmanager
+def coalesced_writes():
+    """Holds this thread's NativeSocketShim writes and sends them in one
+    `sock_write_batch` when the scope closes, exception or not: each
+    connection still gets its frames in order. A nested scope joins the
+    outer one."""
+    if _scope.frames is not None:
+        yield
+        return
+    _scope.frames = []
+    try:
+        yield
+    finally:
+        try:
+            _flush_writes()
+        finally:
+            _scope.frames = None
 
 
 class NativeSocketShim:
@@ -42,10 +80,15 @@ class NativeSocketShim:
 
     def write(self, buf: IOBuf, id_wait=None) -> int:
         data = buf.copy_to_bytes(len(buf))
+        frames = _scope.frames
+        if frames is not None:
+            frames.append((self.sock_id, data))
+            return 0
         return native.sock_write(self.sock_id, data)
 
     def set_failed(self, error_code=0, error_text: str = ""):
         self._failed = True
+        _flush_writes()  # answers held in a scope leave before the fail
         native.sock_set_failed(self.sock_id)
 
     def failed(self) -> bool:

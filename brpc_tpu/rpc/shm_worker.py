@@ -101,6 +101,7 @@ def main(shm_name: str, factory_spec: str) -> int:
     native.http_respond = http_respond
     native.grpc_respond = grpc_respond
     native.sock_write = lambda *a, **k: -1       # no sockets here
+    native.sock_write_batch = lambda *a, **k: 0
     native.sock_set_failed = lambda *a, **k: -1
 
     mod_name, _, fn_name = factory_spec.partition(":")

@@ -27,9 +27,14 @@ brings those rows back, and only then is each op's `done` called.
   reads record 0, which is discarded. Every bucket is compiled when the
   service is built, so none compiles while it serves.
 
+The consumer answers a batch's ops inside one `coalesced_writes()` scope,
+so on the native runtime their frames leave in one native write and the
+consumer gives up the interpreter lock once per batch, not once per op.
+
 Spans (`rpcz.phase`): `table.batch` (the consumer's time for one batch),
 `table.dispatch` (dispatch of the program), `table.wait` (the wait for the
-program and the copy of the rows to the host). Counters (bvar): see
+program and the copy of the rows to the host), `table.answer` (the
+batch's answers, serialized and written). Counters (bvar): see
 `COUNTERS`.
 """
 from __future__ import annotations
@@ -44,6 +49,7 @@ import numpy as np
 from brpc_tpu import bvar, rpcz
 from brpc_tpu.bthread.execution_queue import ExecutionQueue
 from brpc_tpu.rpc import errors
+from brpc_tpu.rpc.native_runtime import coalesced_writes
 from brpc_tpu.rpc.proto import table_service_pb2 as tb_pb2
 from brpc_tpu.rpc.service import Service, rpc_method
 
@@ -196,10 +202,11 @@ class TableService(Service):
                 self._apply(ops)
             except Exception as e:  # every op of the batch is answered
                 _log.exception("table batch failed")
-                for op in ops:
-                    op.cntl.set_failed(errors.EDEVICE,
-                                       f"table batch failed: {e}")
-                    op.done()
+                with coalesced_writes():
+                    for op in ops:
+                        op.cntl.set_failed(errors.EDEVICE,
+                                           f"table batch failed: {e}")
+                        op.done()
         return 0
 
     def _apply(self, ops: List[_Op]):
@@ -224,12 +231,13 @@ class TableService(Service):
         _reads.update(len(reads))
         _updates.update(n_updates)
         _superseded.update(n_updates - len(kept))
-        for i, op in enumerate(reads):
-            op.response.record = rows[i].tobytes()
-        for op in ops:
-            if op.field >= 0:
-                op.response.ok = True
-            op.done()
+        with rpcz.phase("table.answer"), coalesced_writes():
+            for i, op in enumerate(reads):
+                op.response.record = rows[i].tobytes()
+            for op in ops:
+                if op.field >= 0:
+                    op.response.ok = True
+                op.done()
 
     def _run(self, bucket: int, at, values, keys) -> np.ndarray:
         """Runs one batch's program; returns the rows read, on the host."""

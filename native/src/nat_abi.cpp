@@ -300,6 +300,7 @@ int main() {
       NAT_SYM(nat_req_free),
       NAT_SYM(nat_respond),
       NAT_SYM(nat_sock_write),
+      NAT_SYM(nat_sock_write_batch),
       NAT_SYM(nat_sock_set_failed),
       NAT_SYM(nat_http_respond),
       NAT_SYM(nat_sock_graceful_close),

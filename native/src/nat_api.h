@@ -100,6 +100,8 @@ int nat_respond(void* h, int32_t error_code, const char* error_text,
                 const char* payload, size_t payload_len, const char* att,
                 size_t att_len);
 int nat_sock_write(uint64_t sock_id, const char* data, size_t len);
+int nat_sock_write_batch(const char* data, const uint64_t* sock_ids,
+                         const size_t* lens, int n);
 int nat_sock_set_failed(uint64_t sock_id);
 
 // protocol-lane response emitters (nat_http.cpp / nat_h2.cpp / nat_redis.cpp)

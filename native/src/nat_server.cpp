@@ -10,6 +10,8 @@
 // code runs on pthreads, never on fiber stacks.
 #include "nat_internal.h"
 
+#include <algorithm>
+
 namespace brpc_tpu {
 
 NatServer::~NatServer() {
@@ -673,6 +675,44 @@ int nat_sock_write(uint64_t sock_id, const char* data, size_t len) {
   int rc = s->write(std::move(out));
   NAT_REF_RELEASE(s, sock.borrow);
   return rc;
+}
+
+// Many pre-framed writes in one crossing: frame i is lens[i] bytes of
+// `data`, after the frames before it, for connection sock_ids[i]. Each
+// connection gets its frames in their order as one IOBuf and one write;
+// a connection that is gone is skipped. Returns the frames written.
+int nat_sock_write_batch(const char* data, const uint64_t* sock_ids,
+                         const size_t* lens, int n) {
+  if (n <= 0) return 0;
+  std::vector<size_t> at(n);
+  std::vector<int> order(n);
+  size_t off = 0;
+  for (int i = 0; i < n; i++) {
+    at[i] = off;
+    off += lens[i];
+    order[i] = i;
+  }
+  std::stable_sort(order.begin(), order.end(), [sock_ids](int a, int b) {
+    return sock_ids[a] < sock_ids[b];
+  });
+  int written = 0;
+  for (int i = 0; i < n;) {
+    uint64_t id = sock_ids[order[i]];
+    IOBuf out;
+    int j = i;
+    for (; j < n && sock_ids[order[j]] == id; j++) {
+      out.append(data + at[order[j]], lens[order[j]]);
+    }
+    NatSocket* s = sock_address(id);
+    if (s != nullptr) {
+      if (s->write(std::move(out)) == 0) written += j - i;
+      NAT_REF_RELEASE(s, sock.borrow);
+    }
+    i = j;
+  }
+  nat_counter_add(NS_SOCK_WRITE_BATCHES, 1);
+  nat_counter_add(NS_SOCK_WRITE_BATCH_FRAMES, (uint64_t)n);
+  return written;
 }
 
 int nat_sock_set_failed(uint64_t sock_id) {

@@ -133,6 +133,8 @@ static const char* kCounterNames[NS_COUNTER_COUNT] = {
     "nat_autoscale_shrinks",
     "nat_autoscale_blocked",
     "nat_py_queue_wait_ns",
+    "nat_sock_write_batches",
+    "nat_sock_write_batch_frames",
 };
 
 static const char* kLaneNames[NL_LANE_COUNT] = {

@@ -105,6 +105,8 @@ enum NatCounterId : int {
   NS_PY_QUEUE_WAIT_NS,      // summed ns py-lane requests waited from
                             // enqueue_py until their Python dispatch began
                             // (charged from Python, once per taken batch)
+  NS_SOCK_WRITE_BATCHES,    // nat_sock_write_batch calls
+  NS_SOCK_WRITE_BATCH_FRAMES,  // frames handed to nat_sock_write_batch
   NS_COUNTER_COUNT,
 };
 

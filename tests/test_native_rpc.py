@@ -512,3 +512,131 @@ def test_native_port_survives_garbage():
         native.channel_close(h)
     finally:
         native.rpc_server_stop()
+
+
+class SockId(rpc.Service):
+    """Answers each request with the id of the native connection it came
+    on."""
+    SERVICE_NAME = "EchoService"
+
+    @rpc.rpc_method(echo_pb2.EchoRequest, echo_pb2.EchoResponse)
+    def Echo(self, cntl, request, response, done):
+        response.message = str(cntl._server_socket.sock_id)
+        done()
+
+
+def _recv_exact(conn, n):
+    out = b""
+    while len(out) < n:
+        chunk = conn.recv(n - len(out))
+        if not chunk:
+            break
+        out += chunk
+    return out
+
+
+@pytest.fixture
+def two_conns():
+    """Two plain TCP connections to a native-runtime server, each with the
+    server's id for it: [(socket, sock_id), (socket, sock_id)]."""
+    import socket as pysocket
+    import struct
+
+    from brpc_tpu.butil.iobuf import IOBuf
+    from brpc_tpu.rpc.proto import rpc_meta_pb2
+    from brpc_tpu.rpc.tpu_std_protocol import pack_frame
+
+    srv = rpc.Server(rpc.ServerOptions(num_threads=2,
+                                       use_native_runtime=True))
+    srv.add_service(SockId())
+    assert srv.start("127.0.0.1:0") == 0
+    conns = []
+    try:
+        for cid in (1, 2):
+            conn = pysocket.create_connection(
+                ("127.0.0.1", srv.listen_endpoint.port), timeout=10)
+            meta = rpc_meta_pb2.RpcMeta(correlation_id=cid)
+            meta.request.service_name = "EchoService"
+            meta.request.method_name = "Echo"
+            frame = pack_frame(meta, echo_pb2.EchoRequest().
+                               SerializeToString(), IOBuf())
+            conn.sendall(frame.copy_to_bytes(len(frame)))
+            body, meta_size = struct.unpack(">II", _recv_exact(conn, 12)[4:])
+            resp = _recv_exact(conn, body)[meta_size:]
+            conns.append((conn, int(echo_pb2.EchoResponse.FromString(
+                resp).message)))
+        yield conns
+    finally:
+        for conn, _ in conns:
+            conn.close()
+        srv.stop()
+
+
+def test_sock_write_batch_keeps_each_connections_frames_in_order(two_conns):
+    (ca, a), (cb, b) = two_conns
+    frames = [(a, b"a0" * 3), (b, b"b0"), (a, b"a1" * 70000),
+              (b, b"b1" * 5), (a, b"a2")]
+    assert native.sock_write_batch(frames) == len(frames)
+    for conn, sid in two_conns:
+        want = b"".join(data for s, data in frames if s == sid)
+        assert _recv_exact(conn, len(want)) == want
+
+
+def test_sock_write_batch_skips_a_connection_that_is_gone(two_conns):
+    (ca, a), (cb, b) = two_conns
+    assert native.sock_set_failed(a) == 0
+    assert native.sock_write_batch([(a, b"lost"), (b, b"kept"),
+                                    (a, b"lost too")]) == 1
+    assert _recv_exact(cb, 4) == b"kept"
+    assert _recv_exact(ca, 1) == b""  # closed, nothing written
+
+
+def test_sock_write_batch_counts_calls_and_frames(two_conns):
+    (_, a), (cb, b) = two_conns
+    before = native.stats_counters()
+    frames = [(b, b"x"), (a, b"y"), (b, b"z")]
+    assert native.sock_write_batch(frames) == 3
+    assert native.sock_write_batch([]) == 0  # no crossing, nothing counted
+    after = native.stats_counters()
+    assert after["nat_sock_write_batches"] - \
+        before["nat_sock_write_batches"] == 1
+    assert after["nat_sock_write_batch_frames"] - \
+        before["nat_sock_write_batch_frames"] == 3
+    assert _recv_exact(cb, 2) == b"xz"
+
+
+def test_coalesced_writes_send_a_scopes_frames_in_one_crossing(two_conns):
+    """Shim writes inside a scope (a nested one joins it) are held and
+    leave in one sock_write_batch when the outer scope closes, even when
+    it closes on an exception."""
+    from brpc_tpu.butil.iobuf import IOBuf
+    from brpc_tpu.rpc.native_runtime import NativeSocketShim, \
+        coalesced_writes
+
+    (ca, a), (cb, b) = two_conns
+    shims = {a: NativeSocketShim(a), b: NativeSocketShim(b)}
+    frames = [(a, b"first"), (b, b"second"), (a, b"third")]
+    before = native.stats_counters()
+    with pytest.raises(RuntimeError):
+        with coalesced_writes():
+            for sid, data in frames[:2]:
+                shims[sid].write(IOBuf(data))
+            with coalesced_writes():
+                shims[a].write(IOBuf(frames[2][1]))
+            ca.settimeout(0.2)
+            with pytest.raises(OSError):  # held: nothing sent yet
+                ca.recv(1)
+            ca.settimeout(10)
+            raise RuntimeError("the scope still flushes")
+    after = native.stats_counters()
+    assert after["nat_sock_write_batches"] - \
+        before["nat_sock_write_batches"] == 1
+    assert after["nat_sock_write_batch_frames"] - \
+        before["nat_sock_write_batch_frames"] == 3
+    assert _recv_exact(ca, 10) == b"firstthird"
+    assert _recv_exact(cb, 6) == b"second"
+    # outside a scope a write leaves at once, as one native write
+    shims[b].write(IOBuf(b"now"))
+    assert _recv_exact(cb, 3) == b"now"
+    assert native.stats_counters()["nat_sock_write_batches"] == \
+        after["nat_sock_write_batches"]

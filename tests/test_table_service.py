@@ -23,30 +23,48 @@ FIELD_BYTES = WORDS * 4 // FIELDS
 
 
 class Probe(ts.TableService):
-    """The service, recording each batch as it runs it. `gate`, when set,
-    holds the next batch until it is set or `gate_s` passes; `snapshot`,
-    when set, answers reads from that host copy (the control)."""
+    """The service, recording each batch and the thread that runs it.
+    `gate`, when set, holds the next batch until it is set or `gate_s`
+    passes; `snapshot`, when set, answers reads from that host copy (the
+    control); `fail` makes the batch program raise; an op on a key in
+    `close_keys` asks to close its connection."""
 
     def __init__(self, table):
         super().__init__(table)
         self.batches = []
+        self.threads = []
         self.gate = None
         self.gate_s = 10.0
         self.snapshot = None
+        self.fail = False
+        self.close_keys = set()
 
     def _apply(self, ops):
         self.batches.append([
             (op.cntl.log_id, op.key, op.field,
              None if op.words is None else op.words.tobytes(), op.response)
             for op in ops])
+        self.threads.append(threading.get_ident())
+        for op in ops:
+            if op.key in self.close_keys:
+                op.cntl.close_connection()
         super()._apply(ops)
 
     def _run(self, bucket, at, values, keys):
         gate, self.gate = self.gate, None
         if gate is not None:
             gate.wait(self.gate_s)
+        if self.fail:
+            raise RuntimeError("planted batch fault")
         rows = super()._run(bucket, at, values, keys)
         return rows if self.snapshot is None else self.snapshot[keys]
+
+
+def channel(address, connection_type="single"):
+    ch = rpc.Channel(rpc.ChannelOptions(timeout_ms=10000, max_retry=0,
+                                        connection_type=connection_type))
+    assert ch.init(address) == 0
+    return ch
 
 
 @pytest.fixture(scope="module")
@@ -60,9 +78,8 @@ def served():
     srv = rpc.Server(rpc.ServerOptions(use_native_runtime=True))
     srv.add_service(svc)
     assert srv.start("127.0.0.1:0") == 0
-    ch = rpc.Channel(rpc.ChannelOptions(timeout_ms=10000, max_retry=0))
-    assert ch.init(str(srv.listen_endpoint)) == 0
-    yield svc, ch
+    ch = channel(str(srv.listen_endpoint))
+    yield svc, ch, str(srv.listen_endpoint)
     ch.close()
     srv.stop()
     srv.join(10)
@@ -73,10 +90,11 @@ def served():
 def table(served):
     """The service and channel, a reference holding the table as it
     stands, and the batch log cleared."""
-    svc, ch = served
+    svc, ch, _ = served
     host = np.asarray(svc.table)
     ref = TableReference({k: host[k].tobytes() for k in range(RECORDS)})
     svc.batches.clear()
+    svc.threads.clear()
     return svc, ch, ref
 
 
@@ -127,6 +145,61 @@ def wait_for(cond, timeout=30.0):
             return False
         time.sleep(0.01)
     return True
+
+
+def held_batch(svc, chans, plan):
+    """Holds a first batch (a read of key 1) at the gate, queues `plan`
+    behind it, op i on chans[i % len(chans)], and opens the gate: the
+    plan runs as one batch. Returns [(controller, response)] in plan
+    order, and the first batch's."""
+    svc.gate = held = threading.Event()
+    first = [None]
+    head = threading.Thread(
+        target=lambda: first.__setitem__(0, call(chans[0], ("read", 1))))
+    head.start()
+    assert wait_for(lambda: svc.batches)
+    results = [None] * len(plan)
+    left = [len(plan)]
+    done = threading.Event()
+    lock = threading.Lock()
+
+    def on_done(i, cntl, resp):
+        results[i] = (cntl, resp)
+        with lock:
+            left[0] -= 1
+            if not left[0]:
+                done.set()
+
+    for i, op in enumerate(plan):
+        if op[0] == "read":
+            method = "Table.Read"
+            req = tb_pb2.TableReadRequest(key=op[1])
+            resp = tb_pb2.TableReadResponse()
+        else:
+            method = "Table.Update"
+            req = tb_pb2.TableUpdateRequest(key=op[1], field=op[2],
+                                            value=op[3])
+            resp = tb_pb2.TableUpdateResponse()
+        cntl = rpc.Controller()
+        cntl.log_id = 200 + i
+        chans[i % len(chans)].call_method(
+            method, cntl, req, resp,
+            done=lambda c, i=i, r=resp: on_done(i, c, r))
+    assert wait_for(lambda: len(svc._queue._tasks) == len(plan))
+    held.set()
+    assert done.wait(30)
+    head.join(30)
+    assert [len(b) for b in svc.batches] == [1, len(plan)]
+    return results, first[0]
+
+
+@pytest.fixture
+def second_channel(served):
+    """A channel that opens a connection of its own for every call
+    (channels to one server share their "single" connection)."""
+    ch2 = channel(served[2], connection_type="short")
+    yield ch2
+    ch2.close()
 
 
 def assert_table_is(svc, ref):
@@ -193,55 +266,19 @@ def test_repeated_updates_in_a_batch_keep_the_last(table):
     svc, ch, ref = table
     rng = np.random.default_rng(5)
     hot, field = 17, 3
-    held = threading.Event()
-    svc.gate = held
-    first = threading.Thread(target=call, args=(ch, ("read", 1), 1))
-    first.start()  # its batch waits at the gate
-    assert wait_for(lambda: svc.batches)
     plan = [("update", hot, field, rng.bytes(FIELD_BYTES)),
             ("update", hot, field + 1, rng.bytes(FIELD_BYTES)),
             ("update", hot, field, rng.bytes(FIELD_BYTES)),
             ("read", hot),
             ("update", hot, field, rng.bytes(FIELD_BYTES))]
-    results = [None] * len(plan)
-    done = threading.Event()
-    pending = [len(plan)]
-    lock = threading.Lock()
-
-    def on_done(i, cntl, resp):
-        results[i] = (cntl, resp)
-        with lock:
-            pending[0] -= 1
-            if not pending[0]:
-                done.set()
-
-    for i, op in enumerate(plan):
-        if op[0] == "read":
-            req = tb_pb2.TableReadRequest(key=op[1])
-            resp = tb_pb2.TableReadResponse()
-            method = "Table.Read"
-        else:
-            req = tb_pb2.TableUpdateRequest(key=op[1], field=op[2],
-                                            value=op[3])
-            resp = tb_pb2.TableUpdateResponse()
-            method = "Table.Update"
-        cntl = rpc.Controller()
-        cntl.log_id = 100 + i
-        ch.call_method(method, cntl, req, resp,
-                       done=lambda c, i=i, r=resp: on_done(i, c, r))
     before = counters()
-    # every op has reached the queue before the gate opens
-    assert wait_for(lambda: len(svc._queue._tasks) == len(plan))
-    held.set()
-    assert done.wait(30)
-    first.join(30)
-    assert [len(b) for b in svc.batches] == [1, len(plan)]
+    results, _ = held_batch(svc, [ch], plan)
     assert not any(c.failed() for c, _ in results)
     after = counters()
     assert after["table_updates_superseded"] - \
         before["table_updates_superseded"] == 2
     want = replay(svc, ref)
-    assert results[3][1].record == want[103]
+    assert results[3][1].record == want[203]
     # the field holds the write to reach the queue last of the three
     last = [value for _, key, f, value, _ in svc.batches[1]
             if (key, f) == (hot, field)][-1]
@@ -327,6 +364,84 @@ def test_buckets_cover_the_cap_and_are_all_compiled(table):
     with pytest.raises(ValueError):
         ts.bucket_of(ts.BATCH_CAP + 1)
     assert sorted(svc._programs) == list(ts.BUCKETS)
+
+
+def crossings(monkeypatch):
+    """Records every native write crossing as (thread, "write" or
+    "batch", frames)."""
+    seen = []
+    write, write_batch = native.sock_write, native.sock_write_batch
+
+    def counted_write(sock_id, data):
+        seen.append((threading.get_ident(), "write", 1))
+        return write(sock_id, data)
+
+    def counted_batch(items):
+        seen.append((threading.get_ident(), "batch", len(items)))
+        return write_batch(items)
+
+    monkeypatch.setattr(native, "sock_write", counted_write)
+    monkeypatch.setattr(native, "sock_write_batch", counted_batch)
+    return seen
+
+
+def test_a_batchs_answers_leave_in_one_crossing(table, second_channel,
+                                                  monkeypatch):
+    svc, ch, ref = table
+    seen = crossings(monkeypatch)
+    rng = np.random.default_rng(29)
+    plan = [("read", int(k)) if i % 3 else
+            ("update", int(k), i % FIELDS, rng.bytes(FIELD_BYTES))
+            for i, k in enumerate(zipf_keys(rng, 12))]
+    results, first = held_batch(svc, [ch, second_channel], plan)
+    assert not first[0].failed()
+    assert not any(c.failed() for c, _ in results)
+    want = replay(svc, ref)
+    for i, op in enumerate(plan):
+        if op[0] == "read":
+            assert results[i][1].record == want[200 + i]
+        else:
+            assert results[i][1].ok
+    consumer = set(svc.threads)
+    assert [(kind, n) for t, kind, n in seen if t in consumer] == \
+        [("batch", 1), ("batch", len(plan))]
+    assert_table_is(svc, ref)
+
+
+def test_a_close_connection_answer_still_reaches_the_client(
+        table, second_channel):
+    svc, ch, ref = table
+    svc.close_keys = {77}
+    try:
+        # the op that closes its connection is answered first in the
+        # batch; the op behind it, on the other connection, is answered too
+        results, _ = held_batch(svc, [second_channel, ch],
+                                [("read", 77), ("read", 78)])
+    finally:
+        svc.close_keys = set()
+    for (cntl, resp), key in zip(results, (77, 78)):
+        assert not cntl.failed(), cntl.error_text
+        assert resp.record == ref.read(key)
+    cntl, resp = call(ch, ("read", 79))
+    assert not cntl.failed() and resp.record == ref.read(79)
+
+
+def test_a_batch_whose_program_raises_answers_every_op(table, monkeypatch):
+    svc, ch, ref = table
+    seen = crossings(monkeypatch)
+    plan = [("read", 5), ("update", 6, 2, b"u" * FIELD_BYTES), ("read", 7)]
+    svc.fail = True
+    try:
+        results, first = held_batch(svc, [ch], plan)
+    finally:
+        svc.fail = False
+    for cntl, _ in results + [first]:
+        assert cntl.failed() and cntl.error_code_value == errors.EDEVICE
+        assert "planted batch fault" in cntl.error_text
+    consumer = set(svc.threads)
+    assert [(kind, n) for t, kind, n in seen if t in consumer] == \
+        [("batch", 1), ("batch", len(plan))]
+    assert_table_is(svc, ref)  # the failed batch changed nothing
 
 
 def test_reference_batch_applies_updates_before_reads():
