@@ -226,8 +226,8 @@ def sample_contention(seconds: float = 1.0, hz: int = 99) -> str:
         py_report = profilers.contention_profile(seconds, hz)
     except BaseException:
         # disarm the native sampler we armed: leaving g_mu_on set would
-        # make every later window (and BRPC_TPU_BENCH_PROF bench) see
-        # rc == -1 and silently lose extra.contention until restart
+        # make every later window see rc == -1 and silently lose its
+        # native contention report until restart
         if native_mod is not None and owns:
             try:
                 native_mod.mu_prof_stop()

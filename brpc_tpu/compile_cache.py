@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, configured in one place.
 
-chip_smoke.py, bench.py and __graft_entry__.py call enable() before they
-compile. Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and
-no other directory is set. Otherwise the cache lives at a fixed path in
+chip_smoke.py, benchmark/run.py and __graft_entry__.py call enable()
+before they compile. Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it
+itself and no other directory is set. Otherwise the cache lives at a fixed path in
 the checkout: a path derived from a tempdir, a pid or the time would be
 new on every run and never hit.
 """

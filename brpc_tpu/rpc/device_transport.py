@@ -26,6 +26,7 @@ Transfer semantics by locality:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import struct
 import threading
@@ -215,33 +216,78 @@ class DeviceBlockPool:
             q <<= 1
         return q
 
+    def landing(self) -> "Landing":
+        """`with pool.landing() as land: arr = land.put(...)`."""
+        return Landing(self)
+
     def put_via_pool(self, host_u8, np_dtype, shape, device=None):
-        """Host->device put of raw bytes THROUGH pooled memory: the bytes
-        land in a pooled block of `np_dtype`'s word width (donated update
-        — same HBM each time), then a device-side slice+bitcast produces
-        the typed array. Falls back to a plain device_put when the pool
-        is exhausted, the payload is oversized, or a different target
-        device is asked for. Returns a jax.Array of `np_dtype`/`shape`; a
-        dtype the device would narrow (see `device_holds`) is refused."""
+        """Host->device put of raw bytes THROUGH pooled memory: one
+        `Landing.put` in a scope of its own. Returns a ready jax.Array of
+        `np_dtype`/`shape`; a dtype the device would narrow (see
+        `device_holds`) is refused."""
+        with self.landing() as land:
+            return land.put(host_u8, np_dtype, shape, device)
+
+
+class Landing:
+    """One message landing in HBM. Each `put` dispatches one tensor's
+    bytes and returns its array at once; leaving the scope waits for
+    every array landed in it, once, and only then hands the pooled
+    blocks back to the freelist, where the next fill may donate them.
+    Host memory the landings read from (a ring lease, an arena span)
+    may be reused once the scope has closed, on success or failure."""
+
+    def __init__(self, pool: DeviceBlockPool):
+        self._pool = pool
+        self._arrays: List = []
+        self._blocks: List[Tuple[int, object]] = []  # to hand back
+
+    def __enter__(self) -> "Landing":
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+
+        try:
+            if self._arrays:
+                with rpcz.phase("tensor.land_wait"):
+                    jax.block_until_ready(self._arrays)
+        finally:
+            for size_class, block in self._blocks:
+                self._pool.release(size_class, block)
+
+    def track(self, arrays) -> List:
+        """Arrays landed by other means, for the scope's wait."""
+        arrays = list(arrays)
+        self._arrays.extend(arrays)
+        return arrays
+
+    def put(self, host_u8, np_dtype, shape, device=None):
+        """The bytes land in a pooled block of `np_dtype`'s word width
+        (donated update — same HBM each time), then a device-side
+        slice+bitcast produces the typed array. A plain device_put when
+        the pool is exhausted, the payload is oversized, or a different
+        target device is asked for."""
         import jax
         import numpy as np
 
+        pool = self._pool
         if not device_holds(np_dtype):
             raise ValueError(f"put_via_pool: {np.dtype(np_dtype)} would be "
                              f"narrowed on the device (x64 is off)")
         nbytes = int(host_u8.size)
-        target = device or self._device
+        target = device or pool.device
         word = _block_word(np_dtype)
-        got = (self.acquire(nbytes, word) if target == self._device
-               else None)
+        got = pool.acquire(nbytes, word) if target == pool.device else None
         if got is None:
             with rpcz.phase("tensor.device_put"):
-                return jax.device_put(
-                    host_u8.view(np_dtype).reshape(shape), target)
+                arr = jax.device_put(host_u8.view(np_dtype).reshape(shape),
+                                     target)
+            self._arrays.append(arr)
+            return arr
         size_class, buf = got
-        filled = None
         try:
-            padded = min(self._pad_quantum(nbytes), size_class)
+            padded = min(pool._pad_quantum(nbytes), size_class)
             if padded != nbytes:
                 # np.empty + tail zero: one nbytes memcpy plus a small
                 # tail clear, not a full padded zero-fill + copy
@@ -256,22 +302,18 @@ class DeviceBlockPool:
                 # a reinterpretation, no copy: nbytes is a whole number
                 # of elements and the padded length a multiple of 4096
                 filled = fill(buf, staged.view(word))
-            with rpcz.phase("tensor.view"):
-                arr = view(filled, nbytes, np.dtype(np_dtype), tuple(shape))
-            # the pooled buffer may be re-donated the moment it returns
-            # to the freelist: the slice/bitcast read must be complete
-            with rpcz.phase("tensor.land_wait"):
-                arr.block_until_ready()
-            return arr
-        finally:
-            if filled is not None:
-                # `filled` aliases the donated memory; it IS the pool
-                # buffer from here on
-                self.release(size_class, filled)
-            else:
-                # the fill failed mid-donation: buf may be dead — refill
-                # the class with a fresh buffer instead of a poisoned one
-                self.release(size_class, self._new_block(size_class, word))
+        except BaseException:
+            # the fill failed mid-donation: buf may be dead — refill
+            # the class with a fresh buffer instead of a poisoned one
+            pool.release(size_class, pool._new_block(size_class, word))
+            raise
+        # `filled` aliases the donated memory; it IS the pool buffer
+        # from here on, home once the scope's wait has seen the view
+        self._blocks.append((size_class, filled))
+        with rpcz.phase("tensor.view"):
+            arr = view(filled, nbytes, np.dtype(np_dtype), tuple(shape))
+        self._arrays.append(arr)
+        return arr
 
 
 @functools.lru_cache(maxsize=None)
@@ -1179,12 +1221,19 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
     mapped straight out of the sender's shared arena when same-host (the
     recv-zero-copy-into-registered-blocks path, rdma_endpoint.h:214-219).
     With `device`, each tensor lands there unless the device would narrow
-    its dtype (`device_holds`): that one stays a host array, byte-exact."""
+    its dtype (`device_holds`): that one stays a host array, byte-exact.
+    A message lands in one `Landing`, whose one wait finishes the
+    host-to-device copies before ring leases go back to the producer and
+    before the caller ACKs: the sender reuses its span after ACK
+    (retention-until-ACK, rdma_endpoint.h:214)."""
+    import numpy as np
+
     if not meta.tensors:
         return [], None
     spec = meta.tensors[0].sharding_spec or ""
     if spec.startswith("xfer|"):
-        # pull device-to-device from the sender's transfer server
+        # pull device-to-device from the sender's transfer server; the
+        # sender frees its buffers once our pull completes
         import jax
 
         _, addr, uid_s, seq_s = spec.split("|")
@@ -1195,78 +1244,15 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
                                       sharding=sharding)
                  for t in meta.tensors]
         try:
-            with rpcz.phase("tensor.device_put"):
-                arrays = conn.pull(int(uid_s), specs)
-            # the sender frees its buffers once our pull completes —
-            # finish it before the caller ACKs (retention-until-ACK)
-            with rpcz.phase("tensor.land_wait"):
-                jax.block_until_ready(arrays)
+            with default_block_pool().landing() as land, \
+                    rpcz.phase("tensor.device_put"):
+                arrays = land.track(conn.pull(int(uid_s), specs))
         except Exception:
             _xfer_evict(addr)  # redial next time (sender restarts)
             raise
-        return list(arrays), int(seq_s)
+        return arrays, int(seq_s)
     parts = spec.split(":")
-    seq = None
-    if len(parts) >= 3 and parts[-1].isdigit():
-        seq = int(parts[-1])
-    if parts[0] == "ring" and len(parts) == 4:
-        # descriptor-ring fabric: the payload arrived as kind-8 records
-        # in OUR blob arena (the sender wrote it there once); consume the
-        # leases IN PLACE — put_via_pool DMAs straight from the arena
-        # view, and host-side consumers get zero-copy arrays that release
-        # the lease when they die (out-of-order, past this drain).
-        import numpy as np
-
-        base, count = int(parts[1]), int(parts[2])
-        if count != len(meta.tensors):
-            raise ValueError("device transport: ring record count "
-                             f"{count} != {len(meta.tensors)} tensors")
-        leases = []
-        for i in range(count):
-            lease = _fabric_claim(base + i)
-            if lease is None:
-                for l in leases:
-                    l.release()
-                raise ValueError(
-                    f"device transport: ring record {base + i} never "
-                    f"arrived (fabric receiver not draining?)")
-            leases.append(lease)
-        arrays = []
-        bound = set()  # leases now owned by a zero-copy host array
-        try:
-            for i, (t, lease) in enumerate(zip(meta.tensors, leases)):
-                dtype = _np_dtype(t.dtype)
-                mv = lease.view()
-                dev = _landing(device, dtype)
-                if dev is not None:
-                    arr = default_block_pool().put_via_pool(
-                        np.frombuffer(mv, dtype=np.uint8), dtype,
-                        tuple(t.shape), dev)
-                else:
-                    # zero-copy: the array IS the arena span; the lease
-                    # releases when the last view of it is collected.
-                    # Bind the finalizer to the BASE frombuffer array:
-                    # numpy collapses .base chains to it, so any derived
-                    # view (slices of the reshaped array) keeps it — and
-                    # therefore the lease — alive; binding to the
-                    # reshape wrapper would let a slice outlive the span.
-                    flat = np.frombuffer(mv, dtype=dtype)
-                    _bind_lease(flat, lease)
-                    bound.add(i)
-                    arr = flat.reshape(tuple(t.shape))
-                arrays.append(arr)
-        finally:
-            if device is not None:
-                import jax
-
-                # the async H2D copies must finish before the spans are
-                # handed back to the producer's reclaim
-                with rpcz.phase("tensor.land_wait"):
-                    jax.block_until_ready(arrays)
-            for i, lease in enumerate(leases):
-                if i not in bound:
-                    lease.release()
-        return arrays, seq
+    seq = int(parts[-1]) if len(parts) >= 3 and parts[-1].isdigit() else None
     if parts[0] == "inproc" and parts[1].isdigit():
         arrays = inproc_claim(int(parts[1]))
         if arrays is None:
@@ -1280,63 +1266,76 @@ def receive_tensors(meta, attachment: IOBuf, device=None) -> Tuple[List, Optiona
 
             # arrays already on the device pass through as the SAME
             # object; host arrays the sender published land in HBM
-            with rpcz.phase("tensor.device_put"):
-                arrays = [a if (isinstance(a, jax.Array)
-                                and a.devices() == {device})
-                          or _landing(device, a.dtype) is None
-                          else jax.device_put(a, device) for a in arrays]
-            with rpcz.phase("tensor.land_wait"):
-                jax.block_until_ready(arrays)
+            with default_block_pool().landing() as land, \
+                    rpcz.phase("tensor.device_put"):
+                arrays = land.track(
+                    a if (isinstance(a, jax.Array)
+                          and a.devices() == {device})
+                    or _landing(device, a.dtype) is None
+                    else jax.device_put(a, device) for a in arrays)
         return arrays, seq
-    if parts[0] == "shm" and len(parts) == 4:
-        arena = attach_arena(parts[1])
-        if arena is None:
-            raise ValueError(
-                f"device transport: cannot attach shared arena "
-                f"{parts[1]!r} (sender chose the same-host lane but the "
-                f"shm namespace is not shared)")
-        import numpy as np
-
-        arrays = []
-        pos = int(parts[2])
-        for t in meta.tensors:
-            dtype = _np_dtype(t.dtype)
-            view = np.frombuffer(arena.shm.buf, dtype=np.uint8,
-                                 count=t.nbytes, offset=pos)
-            pos += t.nbytes
-            dev = _landing(device, dtype)
-            if dev is not None:
-                # host->device DMA from the mapped arena THROUGH the
-                # device block pool (block_pool.h role: transfer bytes
-                # land in pooled, pre-allocated HBM)
-                arr = default_block_pool().put_via_pool(
-                    view, dtype, tuple(t.shape), dev)
-            else:
-                # own the bytes before ACK lets the sender reuse them
-                arr = np.array(view.view(dtype).reshape(tuple(t.shape)))
-            arrays.append(arr)
-        if device is not None:
-            import jax
-
-            # the async H2D copies must finish before the caller ACKs —
-            # the sender reuses the span after ACK (retention-until-ACK,
-            # rdma_endpoint.h:214)
-            with rpcz.phase("tensor.land_wait"):
-                jax.block_until_ready(arrays)
-        return arrays, seq
-    # wire path: materialize from attachment bytes
-    import numpy as np
-
-    arrays = []
-    for t in meta.tensors:
-        raw = attachment.cutn_bytes(t.nbytes)
-        dtype = _np_dtype(t.dtype)
-        dev = _landing(device, dtype)
-        if dev is not None:
-            arr = default_block_pool().put_via_pool(
-                np.frombuffer(raw, dtype=np.uint8), dtype,
-                tuple(t.shape), dev)
+    # the byte lanes: find each tensor's bytes, then land them
+    leases = []  # ring leases this call still owns
+    try:
+        if parts[0] == "ring" and len(parts) == 4:
+            # descriptor-ring fabric: the payload arrived as kind-8
+            # records in OUR blob arena (the sender wrote it there once);
+            # the leases are consumed IN PLACE
+            base, count = int(parts[1]), int(parts[2])
+            if count != len(meta.tensors):
+                raise ValueError("device transport: ring record count "
+                                 f"{count} != {len(meta.tensors)} tensors")
+            for tag in range(base, base + count):
+                leases.append(_fabric_claim(tag))
+                if leases[-1] is None:
+                    raise ValueError(
+                        f"device transport: ring record {tag} never "
+                        f"arrived (fabric receiver not draining?)")
+            found = [lease.view() for lease in leases]
+        elif parts[0] == "shm" and len(parts) == 4:
+            arena = attach_arena(parts[1])
+            if arena is None:
+                raise ValueError(
+                    f"device transport: cannot attach shared arena "
+                    f"{parts[1]!r} (sender chose the same-host lane but "
+                    f"the shm namespace is not shared)")
+            found, pos = [], int(parts[2])
+            for t in meta.tensors:
+                found.append(arena.shm.buf[pos:pos + t.nbytes])
+                pos += t.nbytes
         else:
-            arr = np.frombuffer(raw, dtype=dtype).reshape(tuple(t.shape))
-        arrays.append(arr)
+            # wire path: materialize from attachment bytes
+            found = [attachment.cutn_bytes(t.nbytes) for t in meta.tensors]
+        arrays = []
+        with (default_block_pool().landing() if device is not None
+              else contextlib.nullcontext()) as land:
+            for i, (t, raw) in enumerate(zip(meta.tensors, found)):
+                dtype = _np_dtype(t.dtype)
+                dev = _landing(device, dtype)
+                if dev is not None:
+                    # host->device DMA straight from the found bytes
+                    # THROUGH the device block pool (block_pool.h role:
+                    # transfer bytes land in pooled, pre-allocated HBM)
+                    arrays.append(land.put(np.frombuffer(raw, np.uint8),
+                                           dtype, tuple(t.shape), dev))
+                    continue
+                arr = np.frombuffer(raw, dtype=dtype)
+                if leases:
+                    # zero-copy: the array IS the arena span; the lease
+                    # releases when the last view of it is collected.
+                    # Bind the finalizer to the BASE frombuffer array:
+                    # numpy collapses .base chains to it, so any derived
+                    # view (slices of the reshaped array) keeps it — and
+                    # therefore the lease — alive; binding to the
+                    # reshape wrapper would let a slice outlive the span.
+                    _bind_lease(arr, leases[i])
+                    leases[i] = None
+                elif parts[0] == "shm":
+                    # own the bytes before ACK lets the sender reuse them
+                    arr = arr.copy()
+                arrays.append(arr.reshape(tuple(t.shape)))
+    finally:
+        for lease in leases:
+            if lease is not None:
+                lease.release()
     return arrays, seq

@@ -327,7 +327,7 @@ int nat_shm_push_tensor(const char* data, size_t len, uint64_t tag);
 int nat_shm_producer_attach(const char* name);
 int nat_shm_fabric_push(const char* data, size_t len, uint64_t tag);
 void* nat_shm_fabric_take(int timeout_ms);
-// transport microbenchmarks (bench.py shm_desc lanes): parent-side push
+// transport microbenchmarks (test load generators): parent-side push
 // loop (returns GB/s) and worker-side native drain loop (returns records)
 double nat_shm_push_bench(size_t record_bytes, double seconds,
                           uint64_t* out_records);

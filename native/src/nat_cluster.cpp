@@ -1124,7 +1124,7 @@ int nat_cluster_stats(void* h, NatClusterRow* out, int max) {
   return n;
 }
 
-// Fan-out bench loop (bench.py fanout lanes + the swarm churn drill):
+// Fan-out bench loop (the swarm churn drill of tools/drills.py):
 // `concurrency` pthreads drive mode 0 (selective; param = max_retry),
 // mode 1 (parallel; param = fail_limit), or mode 2 (dynpart; param =
 // fail_limit — the autoscale drill's flood) calls for `seconds`. Returns

@@ -223,7 +223,8 @@ void Dispatcher::run() {
 // loop 0; accepted/connected sockets go to the next loop in turn.
 // natcheck:leak(nat_rpc_server_start): dispatcher/worker threads run
 // through exit() and pick_dispatcher() must never read a destructed
-// vector (the bench-exit SIGSEGV class, BENCH_r05 rc 139).
+// vector (the exit-time SIGSEGV class: a detached loop outlives
+// static destructors).
 std::vector<Dispatcher*>& g_disps = *new std::vector<Dispatcher*>();
 Dispatcher* g_disp = nullptr;  // g_disps[0]: listeners + console
 NatServer* g_rpc_server = nullptr;
@@ -232,34 +233,10 @@ static std::atomic<uint32_t> g_disp_rr{0};
 static std::atomic<uint32_t> g_disp_rr_cli{0};
 static int g_disp_count = 0;  // 0 = auto (set before first runtime use)
 
-// Dispatcher split (NAT_DISP_SPLIT=1): accepted sockets round-robin over
-// the even loop indices, dialed (client) sockets over the odd ones — an
-// IN-PROCESS loopback bench then stops multiplexing both runtimes' hot
-// sockets through one loop (the cross-runtime interference the
-// single-core bench numbers used to include; bench.py sets it for its
-// in-process lanes). Default OFF: a dedicated server or client process
-// must shard over the WHOLE pool — partitioning there would idle half
-// the loops (measured: a 2-loop server process lost ~30% at 2 cpus).
-static std::atomic<int> g_disp_split{-1};  // -1 = unread
-
+// Independent round-robin per side over the whole pool.
 Dispatcher* pick_dispatcher(bool client_side) {
   size_t n = g_disps.size();
   if (n == 1) return g_disps[0];
-  int split = g_disp_split.load(std::memory_order_relaxed);
-  if (split < 0) {
-    const char* env = getenv("NAT_DISP_SPLIT");
-    split = (env != nullptr && env[0] == '1') ? 1 : 0;
-    g_disp_split.store(split, std::memory_order_relaxed);
-  }
-  if (split == 1) {
-    if (client_side) {
-      uint32_t i = g_disp_rr_cli.fetch_add(1, std::memory_order_relaxed);
-      return g_disps[1 + 2 * (i % (n / 2))];
-    }
-    uint32_t i = g_disp_rr.fetch_add(1, std::memory_order_relaxed);
-    return g_disps[2 * (i % ((n + 1) / 2))];
-  }
-  // unsplit: independent round-robin per side over the whole pool
   uint32_t i = client_side
                    ? g_disp_rr_cli.fetch_add(1, std::memory_order_relaxed)
                    : g_disp_rr.fetch_add(1, std::memory_order_relaxed);

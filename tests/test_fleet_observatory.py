@@ -425,7 +425,7 @@ def _elimit_probe(port, n=6):
 @pytest.mark.skipif(not os.path.exists(GOLDEN),
                     reason="golden capture missing")
 def test_three_process_flood_drill():
-    from brpc_tpu.bench import _spawn_swarm_server
+    from tools.drills import _spawn_swarm_server
     from brpc_tpu.fleet import FleetObservatory, SloObjective
 
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(

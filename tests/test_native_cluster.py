@@ -523,7 +523,7 @@ def test_swarm_churn_zero_failed_rpcs():
     multi-port swarm behind the native cluster survives rolling SIGTERM
     restarts (graceful quiesce + lame-duck) and live naming updates
     with ZERO failed RPCs and a recorded per-backend distribution."""
-    from brpc_tpu.bench import fanout_swarm_bench
+    from tools.drills import fanout_swarm_bench
 
     r = fanout_swarm_bench(backends=120, servers=3, bench_seconds=8.0,
                            concurrency=3)

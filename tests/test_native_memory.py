@@ -288,11 +288,11 @@ def test_churn_balance_shm_worker_sigkill_recover():
 
 @pytest.mark.slow
 def test_conn_scale_drill_small():
-    """The bench.py conn_scale lane at test size: every connection
+    """The connection-scale drill at test size: every connection
     accepted AND answered through the accept storm, zero failed RPCs on
     the live subset, per-connection cost recorded from the accounting,
     and no transient-subsystem leak after teardown."""
-    from brpc_tpu.bench import conn_scale_bench
+    from tools.drills import conn_scale_bench
 
     out = conn_scale_bench(target_conns=240, client_procs=2, idle_s=1.0)
     assert out, "lane disabled?"

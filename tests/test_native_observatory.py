@@ -77,6 +77,12 @@ def server():
         got += sk.recv(4096)
     sk.close()
 
+    # the first page must read the rows this traffic made, not a
+    # 0.25s-TTL snapshot that a sampler left by an earlier module in
+    # this process took before them
+    from brpc_tpu.bvar import native_vars
+
+    native_vars.settle_for_tests()
     yield srv, port
     srv.stop()
 

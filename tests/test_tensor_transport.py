@@ -264,3 +264,159 @@ def test_block_pool_put_via_pool_oversized_falls_back():
                                 jax.devices()[0])
     assert arr_big.shape == (4 << 20,)
     assert pool.stats() == before
+
+
+# -- receive_tensors: every lane lands through one pool scope ---------------
+
+LANES = ["wire", "shm", "ring", "inproc"]
+
+
+class _FakeLease:
+    """A fabric lease over plain host bytes that counts its releases."""
+
+    def __init__(self, tag, raw):
+        self.tag, self._raw, self.releases = tag, bytearray(raw), 0
+
+    def view(self):
+        return memoryview(self._raw)
+
+    def release(self):
+        self.releases += 1
+
+
+@pytest.fixture(scope="module")
+def send_arena():
+    """A named arena the tests write as a same-host sender would. The
+    receiver finds it already attached: a second mapping of a segment
+    this process created would end its registration twice."""
+    arena = dt.HostArena(size=1 << 20)
+    dt._attached_arenas[arena.name] = arena
+    yield arena
+    dt._attached_arenas.pop(arena.name, None)
+    arena.close()
+
+
+def _message(lane, arrays, arena, deliver=None):
+    """(meta, attachment, leases) of a message carrying `arrays` on
+    `lane`; on the ring lane only the records in `deliver` arrive."""
+    import time
+
+    from brpc_tpu.butil.iobuf import IOBuf
+
+    meta, att, leases = rpc_meta_pb2.RpcMeta(), IOBuf(), []
+    for a in arrays:
+        t = meta.tensors.add()
+        t.dtype, t.nbytes = str(a.dtype), int(a.nbytes)
+        t.shape.extend(a.shape)
+    if lane == "wire":
+        spec = "wire::1"
+        for a in arrays:
+            att.append(a.tobytes())
+    elif lane == "shm":
+        spec, pos = f"shm:{arena.name}:0:1", 0
+        buf = np.frombuffer(arena.shm.buf, dtype=np.uint8)
+        for a in arrays:
+            buf[pos:pos + a.nbytes] = np.frombuffer(a.tobytes(), np.uint8)
+            pos += a.nbytes
+    elif lane == "ring":
+        base = (1 << 61) + int(np.random.default_rng().integers(1 << 40))
+        spec = f"ring:{base}:{len(arrays)}:1"
+        leases = [_FakeLease(base + i, a.tobytes())
+                  for i, a in enumerate(arrays)]
+        with dt._fabric_cv:
+            for i, lease in enumerate(leases):
+                if deliver is None or i in deliver:
+                    dt._fabric_records[lease.tag] = (
+                        lease, time.monotonic() + 600)
+            dt._fabric_cv.notify_all()
+    else:
+        spec = f"inproc:{dt.inproc_publish(list(arrays))}:1"
+    meta.tensors[0].sharding_spec = spec
+    return meta, att, leases
+
+
+def _payload(dtype, nbytes, seed):
+    raw = np.random.default_rng(seed).integers(0, 256, nbytes,
+                                               dtype=np.uint8)
+    np_dtype = dt._np_dtype(dtype)
+    if np_dtype == np.bool_:
+        raw &= 1
+    return raw.view(np_dtype).reshape(2, -1)
+
+
+def _warm_word(pool, dtype):
+    """Allocate the dtype's block word up front, so stats() taken before
+    a receive already lists every class that receive may use."""
+    pool.release(*pool.acquire(1, _WORDS[dtype]))
+
+
+@pytest.mark.parametrize("dtype", list(_WORDS))
+@pytest.mark.parametrize("lane", LANES)
+def test_receive_tensors_lands_every_lane(lane, dtype, send_arena):
+    """Each lane's bytes land on the device exactly; every pool block is
+    back home afterwards and each ring lease is released exactly once."""
+    pool = dt.default_block_pool()
+    _warm_word(pool, dtype)
+    src = _payload(dtype, 6000, seed=len(dtype))
+    before = pool.stats()
+    meta, att, leases = _message(lane, [src], send_arena)
+    arrays, seq = dt.receive_tensors(meta, att, device=pool.device)
+    assert seq == 1
+    (arr,) = arrays
+    assert arr.devices() == {pool.device}
+    assert arr.dtype == src.dtype and arr.shape == src.shape
+    assert np.asarray(arr).tobytes() == src.tobytes()
+    assert pool.stats() == before
+    assert [lease.releases for lease in leases] == [1] * len(leases)
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_receive_tensors_waits_once(lane, send_arena, monkeypatch):
+    """A two-tensor message makes exactly one block_until_ready."""
+    import jax
+
+    waits = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        waits.append(x)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    src = [_payload("float32", 6000, seed=1), _payload("int8", 8192, 2)]
+    meta, att, _ = _message(lane, src, send_arena)
+    arrays, _ = dt.receive_tensors(meta, att,
+                                   device=dt.default_block_pool().device)
+    assert len(waits) == 1
+    assert len(waits[0]) == 2
+    for got, want in zip(arrays, src):
+        assert np.asarray(got).tobytes() == want.tobytes()
+
+
+def test_message_beyond_pool_blocks_lands_exactly():
+    """Ten tensors of one size class into a pool of eight blocks there:
+    the extra tensors land by a plain device_put, bytes unchanged, and
+    every block goes home."""
+    pool = dt.default_block_pool()
+    _warm_word(pool, "float32")
+    src = [_payload("float32", 100 << 10, seed=i) for i in range(10)]
+    before = pool.stats()
+    meta, att, _ = _message("wire", src, None)
+    arrays, _ = dt.receive_tensors(meta, att, device=pool.device)
+    for got, want in zip(arrays, src):
+        assert np.asarray(got).tobytes() == want.tobytes()
+    assert pool.stats() == before
+
+
+def test_ring_missing_record_releases_claimed_leases(monkeypatch):
+    """A ring message whose second record never arrives raises, and the
+    lease already claimed goes back exactly once."""
+    claim = dt._fabric_claim
+    monkeypatch.setattr(dt, "_fabric_claim",
+                        lambda tag, timeout_s=10.0: claim(tag, 0.05))
+    src = [_payload("float32", 6000, seed=i) for i in range(2)]
+    meta, att, leases = _message("ring", src, None, deliver={0})
+    with pytest.raises(ValueError, match="never arrived"):
+        dt.receive_tensors(meta, att,
+                           device=dt.default_block_pool().device)
+    assert [lease.releases for lease in leases] == [1, 0]

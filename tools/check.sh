@@ -37,11 +37,6 @@
 # committed corpus + regress inputs for NATCHECK_FUZZ_MS (default
 # 2000ms) each; any crash or sanitizer report fails (see
 # tools/natcheck/fuzzlane.py).
-# --bench (or NATCHECK_BENCH=1) runs the perf regression gate: bench.py
-# with the nat_prof flight recorder attached, a schema'd artifact
-# (BENCH_latest.json), and a headline-lane diff against the last
-# committed BENCH_r*.json — >15% regression on a stable lane hard-fails
-# with that lane's profile attached (see tools/natcheck/benchgate.py).
 # Exits nonzero on any finding.
 set -u
 
@@ -52,7 +47,6 @@ RC=0
 
 SOAK="${NATCHECK_SOAK:-0}"
 CHAOS="${NATCHECK_CHAOS:-0}"
-BENCH="${NATCHECK_BENCH:-0}"
 REFGUARD="${NATCHECK_REFGUARD:-0}"
 REPLAY="${NATCHECK_REPLAY:-0}"
 FLEET="${NATCHECK_FLEET:-0}"
@@ -61,7 +55,6 @@ for arg in "$@"; do
     case "$arg" in
         --soak) SOAK=1 ;;
         --chaos) CHAOS=1 ;;
-        --bench) BENCH=1 ;;
         --refguard) REFGUARD=1 ;;
         --replay) REPLAY=1 ;;
         --fleet) FLEET=1 ;;
@@ -181,19 +174,6 @@ print("natcheck: fuzz: %s"
 print_findings(findings)
 sys.exit(1 if findings else 0)
 PYFZ
-fi
-
-if [ "$BENCH" = "1" ]; then
-    "$PY" - <<'EOF' || RC=1
-import sys
-sys.path.insert(0, ".")
-from tools.natcheck import print_findings, benchgate
-findings = benchgate.run()
-print("natcheck: bench: %s (artifact: BENCH_latest.json)"
-      % ("clean" if not findings else "%d finding(s)" % len(findings)))
-print_findings(findings)
-sys.exit(1 if findings else 0)
-EOF
 fi
 
 if [ "$CHAOS" = "1" ]; then

@@ -1,12 +1,13 @@
 #!/usr/bin/env python
-"""Regenerate the committed golden capture benchgate replays.
+"""Regenerate the committed golden capture the fleet drill replays.
 
 tests/data/golden_capture_1k.rio is a deterministic 1000-request
 tpu_std capture (seeded payload sizes, recordio format —
-butil/recordio.py) that bench.py's ``replay_qps`` lane re-fires through
-the native replay client against the bench echo server. Committing the
-capture (not just this generator) keeps the lane byte-stable across
-rounds: a qps change is a runtime regression, never a workload drift.
+butil/recordio.py) that tests/test_fleet_observatory.py re-fires
+through the native replay client against a live server group.
+Committing the capture (not just this generator) keeps the drill's
+traffic byte-stable: a change in what it sees is a runtime change,
+never a workload drift.
 
 Usage: python tools/make_golden_capture.py [out_path]
 """

@@ -30,11 +30,8 @@ Six passes over the C++ core and its FFI boundary (see README.md here):
   to the full native matrix and logs native/SOAK.md.
 
 Standing check.sh-only lanes: ``--refguard`` (the refown runtime twin
-over the C smoke + pytest native matrix, refguard.py), ``--chaos``
-(fixed-seed fault-injection soak, chaos.py) and ``--bench`` (the perf
-regression gate, benchgate.py:
-bench.py + nat_prof profile -> schema'd artifact -> headline-lane diff
-against the last committed BENCH_r*.json with tolerance bands).
+over the C smoke + pytest native matrix, refguard.py) and ``--chaos``
+(fixed-seed fault-injection soak, chaos.py).
 
 Entry points: ``python -m tools.natcheck`` or ``make -C native check``
 (which delegates to tools/check.sh).

@@ -174,7 +174,7 @@ def _swarm_leg() -> Tuple[List[Finding], str]:
     findings: List[Finding] = []
     env = dict(os.environ)
     env.pop("NAT_FAULT", None)  # the CLIENT side stays clean; servers
-    env["BRPC_TPU_CHURN_FAULT"] = CHURN_SPEC  # armed via the bench hook
+    env["BRPC_TPU_CHURN_FAULT"] = CHURN_SPEC  # armed via the drill hook
     env["JAX_PLATFORMS"] = "cpu"
     try:
         proc = subprocess.run(

@@ -35,7 +35,7 @@ def run() -> List[Finding]:
 
     sys.path.insert(0, REPO_ROOT)
     from brpc_tpu import native  # noqa: F401 — fail early when .so missing
-    from brpc_tpu.bench import _spawn_swarm_server
+    from tools.drills import _spawn_swarm_server
     from brpc_tpu.fleet import FleetObservatory, SloObjective, hist
 
     env = dict(os.environ)
