@@ -107,6 +107,31 @@ class NatConnRow(ctypes.Structure):
     ]
 
 
+class NatReqInfo(ctypes.Structure):
+    """Mirror of nat_api.h NatReqInfo — one py-lane request: its handle,
+    scalar fields, and a (pointer, length) view of each byte field."""
+
+    _fields_ = [
+        ("handle", ctypes.c_void_p),
+        ("kind", ctypes.c_int32),
+        ("compress_type", ctypes.c_int32),
+        ("sock_id", ctypes.c_uint64),
+        ("cid", ctypes.c_int64),
+        ("aux", ctypes.c_uint64),
+        ("enqueue_ns", ctypes.c_uint64),
+        ("service", ctypes.c_void_p),
+        ("service_len", ctypes.c_size_t),
+        ("method", ctypes.c_void_p),
+        ("method_len", ctypes.c_size_t),
+        ("payload", ctypes.c_void_p),
+        ("payload_len", ctypes.c_size_t),
+        ("attachment", ctypes.c_void_p),
+        ("attachment_len", ctypes.c_size_t),
+        ("meta", ctypes.c_void_p),
+        ("meta_len", ctypes.c_size_t),
+    ]
+
+
 class NatResRow(ctypes.Structure):
     """Mirror of nat_res.h NatResRow — one per-subsystem resource-ledger
     row (live bytes/objects, cumulative allocs/frees, high-water)."""
@@ -265,8 +290,6 @@ def load() -> ctypes.CDLL:
         lib.nat_rpc_server_stop.restype = None
         lib.nat_rpc_server_requests.restype = ctypes.c_uint64
         lib.nat_rpc_server_connections.restype = ctypes.c_uint64
-        lib.nat_take_request.argtypes = [ctypes.c_int]
-        lib.nat_take_request.restype = ctypes.c_void_p
         lib.nat_req_field.argtypes = [
             ctypes.c_void_p, ctypes.c_int, ctypes.POINTER(ctypes.c_size_t)]
         lib.nat_req_field.restype = ctypes.c_void_p
@@ -391,9 +414,12 @@ def load() -> ctypes.CDLL:
         lib.nat_channel_breaker_state.restype = ctypes.c_int
         lib.nat_channel_retry_budget.argtypes = [ctypes.c_void_p]
         lib.nat_channel_retry_budget.restype = ctypes.c_int
-        lib.nat_take_request_batch.argtypes = [
-            ctypes.POINTER(ctypes.c_void_p), ctypes.c_int, ctypes.c_int]
-        lib.nat_take_request_batch.restype = ctypes.c_int
+        lib.nat_take_request_batch_info.argtypes = [
+            ctypes.POINTER(NatReqInfo), ctypes.c_int, ctypes.c_int]
+        lib.nat_take_request_batch_info.restype = ctypes.c_int
+        lib.nat_req_info.argtypes = [ctypes.c_void_p,
+                                     ctypes.POINTER(NatReqInfo)]
+        lib.nat_req_info.restype = None
         lib.nat_http_client_bench.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
             ctypes.c_double, ctypes.c_char_p, ctypes.c_char_p,
@@ -809,81 +835,65 @@ def take_request(timeout_ms: int = 100):
     connection-ordered response token for http_respond); 4 =
     native-parsed gRPC request (f1 = :path, payload = gRPC-framed body,
     seq = h2 stream id); 5 = streaming frame (aux = dest stream id,
-    payload = frame body, seq orders frames per socket). aux is 0 except
-    for kind 5. enqueue_ns is the CLOCK_MONOTONIC time (time.monotonic_ns)
-    at which the request entered the queue, 0 for kind 2."""
-    lib = load()
-    h = lib.nat_take_request(timeout_ms)
-    if not h:
-        return None
-    kind = lib.nat_req_kind(h)
-    enq = lib.nat_req_enqueue_ns(h)
-    def field(which):
-        n = ctypes.c_size_t(0)
-        p = lib.nat_req_field(h, which, ctypes.byref(n))
-        return ctypes.string_at(p, n.value) if p and n.value else b""
+    f0 = frame type, payload = frame body, seq orders frames per socket;
+    a body of 64 KiB or more is a zero-copy read-only view that owns the
+    request, and the handle slot is None); 6 = native-parsed RESP command
+    (payload). aux is 0 except for kind 5. enqueue_ns is the
+    CLOCK_MONOTONIC time (time.monotonic_ns) at which the request entered
+    the queue, 0 for kind 2."""
+    items = take_requests(1, timeout_ms)
+    return items[0] if items else None
+
+
+def _view(p, n):
+    # string_at is a PYFUNCTYPE helper: it copies with the GIL held
+    return ctypes.string_at(p, n) if p and n else b""
+
+
+def req_info(h) -> NatReqInfo:
+    """The record of one request handle, read in one crossing."""
+    rec = NatReqInfo()
+    load().nat_req_info(h, ctypes.byref(rec))
+    return rec
+
+
+def req_tuple(rec: NatReqInfo):
+    """The take_request tuple of one record; reads no native accessor."""
+    h = rec.handle
+    kind = rec.kind
     if kind in (3, 4):  # native-parsed HTTP / gRPC-over-h2
-        return (h, kind, field(4), field(2), b"",
-                lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                field(0), field(1), 0, enq)
-    if kind == 5:  # native-cut streaming frame: aux = dest stream id,
-        # f0 = frame type (same contract as take_requests), cid = order
-        return (h, kind, b"", field(2), b"", lib.nat_req_sock_id(h),
-                lib.nat_req_cid(h), lib.nat_req_compress(h), b"",
-                lib.nat_req_aux(h), enq)
-    return (h, kind, field(4), field(2), field(3),
-            lib.nat_req_sock_id(h), lib.nat_req_cid(h), b"", b"", 0, enq)
+        return (h, kind, _view(rec.meta, rec.meta_len),
+                _view(rec.payload, rec.payload_len), b"", rec.sock_id,
+                rec.cid, _view(rec.service, rec.service_len),
+                _view(rec.method, rec.method_len), 0, rec.enqueue_ns)
+    if kind == 5:  # native-cut streaming frame: f0 = frame type
+        p, n = rec.payload, rec.payload_len
+        if p and n >= 65536:
+            # big stream payload: wrap the native buffer read-only with
+            # ZERO copy; the request handle is freed when the last view
+            # of the buffer is garbage-collected, so a handler retaining
+            # the message stays safe. Ownership moved here: handle None.
+            cbuf = (ctypes.c_char * n).from_address(p)
+            weakref.finalize(cbuf, load().nat_req_free, h)
+            payload, h = memoryview(cbuf).toreadonly(), None
+        else:
+            payload = _view(p, n)
+        return (h, kind, b"", payload, b"", rec.sock_id, rec.cid,
+                rec.compress_type, b"", rec.aux, rec.enqueue_ns)
+    return (h, kind, _view(rec.meta, rec.meta_len),
+            _view(rec.payload, rec.payload_len),
+            _view(rec.attachment, rec.attachment_len), rec.sock_id,
+            rec.cid, b"", b"", 0, rec.enqueue_ns)
 
 
 def take_requests(max_items: int = 16, timeout_ms: int = 100):
-    """Batch take: one condvar round + one FFI crossing per burst. Returns
-    a list of the same tuples take_request yields (possibly empty)."""
-    lib = load()
-    arr = (ctypes.c_void_p * max_items)()
-    n = lib.nat_take_request_batch(arr, max_items, timeout_ms)
-    out = []
-    for i in range(n):
-        h = arr[i]
-        kind = lib.nat_req_kind(h)
-        enq = lib.nat_req_enqueue_ns(h)
-
-        def field(which, h=h):
-            ln = ctypes.c_size_t(0)
-            p = lib.nat_req_field(h, which, ctypes.byref(ln))
-            return ctypes.string_at(p, ln.value) if p and ln.value else b""
-
-        if kind in (3, 4):
-            out.append((h, kind, field(4), field(2), b"",
-                        lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                        field(0), field(1), 0, enq))
-        elif kind == 5:
-            # frame type rides in the f0 slot (the zero-copy path below
-            # hands the handle to a finalizer, so it can't be queried
-            # at dispatch time)
-            ftype = lib.nat_req_compress(h)
-            ln = ctypes.c_size_t(0)
-            p = lib.nat_req_field(h, 2, ctypes.byref(ln))
-            if p and ln.value >= 65536:
-                # big stream payload: wrap the native buffer read-only
-                # with ZERO copy; the request handle is freed when the
-                # last view of the buffer is garbage-collected, so a
-                # handler retaining the message stays safe. The handle
-                # slot in the tuple is None: ownership moved here.
-                cbuf = (ctypes.c_char * ln.value).from_address(p)
-                weakref.finalize(cbuf, lib.nat_req_free, h)
-                payload = memoryview(cbuf).toreadonly()
-                out.append((None, kind, b"", payload, b"",
-                            lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                            ftype, b"", lib.nat_req_aux(h), enq))
-                continue
-            out.append((h, kind, b"", field(2), b"",
-                        lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                        ftype, b"", lib.nat_req_aux(h), enq))
-        else:
-            out.append((h, kind, field(4), field(2), field(3),
-                        lib.nat_req_sock_id(h), lib.nat_req_cid(h),
-                        b"", b"", 0, enq))
-    return out
+    """Batch take: one condvar round and ONE FFI crossing per burst, which
+    returns every field of every request taken (so a worker gives up the
+    GIL once per take, not per field). Returns a list of take_request
+    tuples (possibly empty)."""
+    recs = (NatReqInfo * max_items)()
+    n = load().nat_take_request_batch_info(recs, max_items, timeout_ms)
+    return [req_tuple(recs[i]) for i in range(n)]
 
 
 def rpc_server_enable_raw_fallback(enable: bool = True) -> int:

@@ -4,10 +4,10 @@ The parent's native runtime parses HTTP/gRPC requests and fans kind-3/4
 dispatch across N of these processes over the zero-copy descriptor-ring
 transport — fixed 64-byte descriptors on lock-free per-worker rings,
 payload bytes written once into a shared blob arena and handed to this
-process as views (nat_req_field points straight into the arena; the copy
-below into Python bytes is the only one on the worker side). Python
-usercode scales past one interpreter's GIL the way the reference runs
-usercode on all N workers (server.h:59-285 num_threads,
+process as views (the request record's fields point straight into the
+arena; the copy below into Python bytes is the only one on the worker
+side). Python usercode scales past one interpreter's GIL the way the
+reference runs usercode on all N workers (server.h:59-285 num_threads,
 details/usercode_backup_pool.h:29-72).
 
 This process holds its slot's ROBUST lifetime fence from attach until
@@ -25,7 +25,6 @@ same constraint every prefork server imposes on app state).
 """
 from __future__ import annotations
 
-import ctypes
 import importlib
 import sys
 
@@ -117,11 +116,6 @@ def main(shm_name: str, factory_spec: str) -> int:
     register_builtin_services(server)
     mount = NativeRuntimeMount(server, num_threads=1)
 
-    def field(h, which):
-        n = ctypes.c_size_t(0)
-        p = lib.nat_req_field(h, which, ctypes.byref(n))
-        return ctypes.string_at(p, n.value) if p and n.value else b""
-
     import os
 
     while True:
@@ -133,20 +127,16 @@ def main(shm_name: str, factory_spec: str) -> int:
             if os.getppid() == 1:
                 return 0
             continue
-        kind = lib.nat_req_kind(h)
-        if kind == 8:
+        rec = native.req_info(h)  # every field in one crossing
+        if rec.kind == 8:
             # bulk tensor record: deliver to the registered tensor sink
             # as an out-of-order-releasable lease (unregistered sinks
             # count the drop instead of losing it silently)
             dispatch_tensor_record(native, h)
             continue
-        sock_id = lib.nat_req_sock_id(h)
-        seq = lib.nat_req_cid(h)
-        verb_or_blank = field(h, 0)
-        path = field(h, 1)
-        headers = field(h, 4)
-        payload = field(h, 2)
-        lib.nat_req_free(h)  # field() copied out: the arena span frees
+        (_, kind, headers, payload, _, sock_id, seq, verb_or_blank, path,
+         _, _) = native.req_tuple(rec)
+        lib.nat_req_free(h)  # req_tuple copied out: the arena span frees
         try:
             if kind == 3:
                 mount._handle_http(verb_or_blank, path, headers, payload,

@@ -61,6 +61,7 @@ NAT_TY(brpc_tpu::NatDumpStatusRec, "struct:NatDumpStatusRec");
 NAT_TY(brpc_tpu::NatReplayResult, "struct:NatReplayResult");
 NAT_TY(brpc_tpu::NatClusterRow, "struct:NatClusterRow");
 NAT_TY(brpc_tpu::NatResRow, "struct:NatResRow");
+NAT_TY(brpc_tpu::NatReqInfo, "struct:NatReqInfo");
 #undef NAT_TY
 
 template <typename T>
@@ -137,6 +138,7 @@ int main() {
   using brpc_tpu::NatLockRankRow;
   using brpc_tpu::NatMethodStatRow;
   using brpc_tpu::NatReplayResult;
+  using brpc_tpu::NatReqInfo;
   using brpc_tpu::NatResRow;
   using brpc_tpu::NatSpanRec;
 #define NAT_FIELD(S, F) \
@@ -249,6 +251,27 @@ int main() {
                    NAT_FIELD(NatClusterRow, endpoint),
                    NAT_FIELD(NatClusterRow, tag),
                },
+               false);
+  print_struct("NatReqInfo", sizeof(NatReqInfo),
+               {
+                   NAT_FIELD(NatReqInfo, handle),
+                   NAT_FIELD(NatReqInfo, kind),
+                   NAT_FIELD(NatReqInfo, compress_type),
+                   NAT_FIELD(NatReqInfo, sock_id),
+                   NAT_FIELD(NatReqInfo, cid),
+                   NAT_FIELD(NatReqInfo, aux),
+                   NAT_FIELD(NatReqInfo, enqueue_ns),
+                   NAT_FIELD(NatReqInfo, service),
+                   NAT_FIELD(NatReqInfo, service_len),
+                   NAT_FIELD(NatReqInfo, method),
+                   NAT_FIELD(NatReqInfo, method_len),
+                   NAT_FIELD(NatReqInfo, payload),
+                   NAT_FIELD(NatReqInfo, payload_len),
+                   NAT_FIELD(NatReqInfo, attachment),
+                   NAT_FIELD(NatReqInfo, attachment_len),
+                   NAT_FIELD(NatReqInfo, meta),
+                   NAT_FIELD(NatReqInfo, meta_len),
+               },
                true);
 #undef NAT_FIELD
   printf("  },\n");
@@ -288,8 +311,8 @@ int main() {
       NAT_SYM(nat_ring_counters),
       NAT_SYM(nat_disp_count),
       NAT_SYM(nat_disp_stat),
-      NAT_SYM(nat_take_request),
-      NAT_SYM(nat_take_request_batch),
+      NAT_SYM(nat_take_request_batch_info),
+      NAT_SYM(nat_req_info),
       NAT_SYM(nat_req_kind),
       NAT_SYM(nat_req_field),
       NAT_SYM(nat_req_cid),

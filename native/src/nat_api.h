@@ -30,6 +30,30 @@ struct NatDumpStatusRec;  // flight-recorder status snapshot (nat_dump.h)
 struct NatReplayResult;   // replay run result (nat_dump.h)
 struct NatClusterRow;     // per-backend cluster snapshot row (nat_stats.h)
 struct NatResRow;         // per-subsystem resource-accounting row (nat_res.h)
+
+// One py-lane request as nat_take_request_batch_info hands it over: the
+// handle (still owned by the caller, freed with nat_req_free), its scalar
+// fields, and a view of each of its five byte fields, as nat_req_field
+// serves them (valid until the handle is freed). Mirrored in ctypes.
+struct NatReqInfo {
+  void* handle;
+  int32_t kind;
+  int32_t compress_type;
+  uint64_t sock_id;
+  int64_t cid;
+  uint64_t aux;
+  uint64_t enqueue_ns;
+  const char* service;
+  size_t service_len;
+  const char* method;
+  size_t method_len;
+  const char* payload;
+  size_t payload_len;
+  const char* attachment;
+  size_t attachment_len;
+  const char* meta;
+  size_t meta_len;
+};
 }
 
 extern "C" {
@@ -86,8 +110,12 @@ int nat_disp_stat(int idx, uint64_t* sockets_out, uint64_t* wakeups_out,
                   int* sqpoll_out);
 
 // py-lane request handoff
-void* nat_take_request(int timeout_ms);
-int nat_take_request_batch(void** out, int max, int timeout_ms);
+// Batch take: fills one record per request taken (at most `max`), so the
+// caller reads every field without a crossing per field. Returns the count.
+int nat_take_request_batch_info(brpc_tpu::NatReqInfo* out, int max,
+                                int timeout_ms);
+// The record of one handle (what the batch take fills for each).
+void nat_req_info(void* h, brpc_tpu::NatReqInfo* out);
 int32_t nat_req_kind(void* h);
 const char* nat_req_field(void* h, int which, size_t* len);
 int64_t nat_req_cid(void* h);

@@ -671,8 +671,8 @@ class NatServer {
   std::atomic<uint64_t> connections{0};
   // Lifetime (replaces the round-2 graveyard): the global registration
   // holds one reference, every accepted socket one, every py-lane taker
-  // one while inside take_py — a stopped server is deleted when the last
-  // connection/taker lets go, and stop->start cycles no longer leak
+  // one while inside take_py_batch — a stopped server is deleted when the
+  // last connection/taker lets go, and stop->start cycles no longer leak
   // (server.h:426-441 Stop/Join-then-Start-again semantics).
   std::atomic<int> ref{1};
 
@@ -781,42 +781,10 @@ class NatServer {
     py_cv.notify_one();
   }
 
-  PyRequest* take_py(int timeout_ms) {
-    // queue-deadline drop: requests that sat longer than the configured
-    // budget are rejected HERE, before a Python worker spends usercode
-    // time on them — the ELIMIT emits happen after py_mu is released
-    // (the responders take session locks that rank below it).
-    PyRequest* r = nullptr;
-    PyRequest* expired[8];
-    int nexp = 0;
-    {
-      std::unique_lock lk(py_mu);
-      if (py_q.empty() && !py_stopping) {
-        nat_cv_wait_for(py_cv, lk, std::chrono::milliseconds(timeout_ms));
-      }
-      uint64_t now = g_overload_on.load(std::memory_order_relaxed) != 0
-                         ? nat_now_ns()
-                         : 0;
-      while (!py_q.empty()) {
-        PyRequest* f = py_q.front();
-        if (now == 0 || !overload_expired(f, now)) {
-          py_q.pop_front();
-          r = f;
-          break;
-        }
-        // expired: never hand it to usercode — when this call's drop
-        // budget is spent, leave the rest queued for the next take
-        if (nexp >= 8) break;
-        py_q.pop_front();
-        expired[nexp++] = f;
-      }
-    }
-    for (int i = 0; i < nexp; i++) overload_expire(expired[i]);
-    return r;
-  }
-
-  // Batch take: one condvar round + one FFI crossing covers a whole
-  // burst (the py lane's per-item wakeup was measurable at qps scale).
+  // Batch take: one condvar round covers a whole burst (the py lane's
+  // per-item wakeup was measurable at qps scale). Requests that sat past
+  // the queue deadline are rejected here, after py_mu is released (the
+  // responders take session locks that rank below it).
   int take_py_batch(PyRequest** out, int max, int timeout_ms) {
     PyRequest* expired[16];
     int nexp = 0;
@@ -844,6 +812,7 @@ class NatServer {
       }
     }
     for (int i = 0; i < nexp; i++) overload_expire(expired[i]);
+    if (n > 0) nat_counter_add(NS_PY_TAKES, 1);
     return n;
   }
 };

@@ -11,7 +11,7 @@
 //   * auto (gradient) limiter — EMA of no-load latency + windowed qps,
 //     limit ≈ capacity * (1 + alpha), min-latency re-probed periodically;
 //   * queue-deadline drop — requests that sat in the py queue past the
-//     budget are rejected BEFORE dispatch (take_py / take_py_batch), so
+//     budget are rejected BEFORE dispatch (take_py_batch), so
 //     a burst cannot convert into unbounded tail latency;
 //   * real wire rejections — tpu_std ELIMIT(2004) frames, HTTP 503,
 //     gRPC RESOURCE_EXHAUSTED(8), RESP -ERR — emitted from the enqueue

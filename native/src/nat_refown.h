@@ -97,7 +97,7 @@ NAT_REF_TAG(srv.sock, "an accepted connection's server reference; "
             "NatSocket::release drops it at slot recycle")
 NAT_REF_TAG(srv.accept, "the dispatcher's accept-burst pin, taken under "
             "listen_mu so a racing stop cannot free the server")
-NAT_REF_TAG(srv.taker, "a py-lane taker inside take_py/take_py_batch")
+NAT_REF_TAG(srv.taker, "a py-lane taker inside take_py_batch")
 NAT_REF_TAG(srv.quiesce, "nat_server_quiesce's drain-scan pin")
 
 // IOBuf blocks (IOBlock::ref; recycles to the block pools at 0):

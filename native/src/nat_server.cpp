@@ -5,9 +5,9 @@
 // nat_messenger.cpp); EPOLLOUT wakes the socket's KeepWrite butex.
 // NatServer ⇔ brpc::Server + Acceptor (server.cpp): native method registry
 // dispatched on fibers/IOBuf, plus a Python lane — a condvar MPSC queue
-// Python worker threads drain via ctypes (nat_take_request/nat_respond),
-// so arbitrary Python services mount the native port while Python user
-// code runs on pthreads, never on fiber stacks.
+// Python worker threads drain via ctypes (nat_take_request_batch_info,
+// nat_respond), so arbitrary Python services mount the native port while
+// Python user code runs on pthreads, never on fiber stacks.
 #include "nat_internal.h"
 
 #include <algorithm>
@@ -576,36 +576,9 @@ uint64_t nat_rpc_server_connections() {
 
 // ---- Python lane (usercode on pthreads) ----
 
-void* nat_take_request(int timeout_ms) {
-  NatServer* srv;
-  {
-    std::lock_guard g(g_rt_mu);
-    srv = g_rpc_server;
-    if (srv == nullptr) return nullptr;
-    // keeps the server alive across the blocking wait
-    NAT_REF_ACQUIRE(srv, srv.taker);
-  }
-  void* r = srv->take_py(timeout_ms);
-  NAT_REF_RELEASE(srv, srv.taker);
-  return r;
-}
-
-// Batch variant: fills up to `max` handles, returns the count.
-int nat_take_request_batch(void** out, int max, int timeout_ms) {
-  NatServer* srv;
-  {
-    std::lock_guard g(g_rt_mu);
-    srv = g_rpc_server;
-    if (srv == nullptr) return 0;
-    NAT_REF_ACQUIRE(srv, srv.taker);
-  }
-  int n = srv->take_py_batch((PyRequest**)out, max, timeout_ms);
-  NAT_REF_RELEASE(srv, srv.taker);
-  return n;
-}
-
-const char* nat_req_field(void* h, int which, size_t* len) {
-  PyRequest* r = (PyRequest*)h;
+// Field `which` (0 service, 1 method, 2 payload, 3 attachment, 4 meta) of
+// a py-lane request, as nat_req_field and nat_req_info serve it.
+static const char* req_field(PyRequest* r, int which, size_t* len) {
   if (r->shm_slot >= 0) {
     // shm descriptor-lane request: fields are views straight into the
     // mapped blob arena (valid until nat_req_free releases the span)
@@ -633,6 +606,46 @@ const char* nat_req_field(void* h, int which, size_t* len) {
   }
   *len = s->size();
   return s->data();
+}
+
+const char* nat_req_field(void* h, int which, size_t* len) {
+  return req_field((PyRequest*)h, which, len);
+}
+
+void nat_req_info(void* h, NatReqInfo* out) {
+  PyRequest* r = (PyRequest*)h;
+  out->handle = h;
+  out->kind = r->kind;
+  out->compress_type = r->compress_type;
+  out->sock_id = r->sock_id;
+  out->cid = r->cid;
+  out->aux = r->aux;
+  out->enqueue_ns = r->enqueue_ns;
+  out->service = req_field(r, 0, &out->service_len);
+  out->method = req_field(r, 1, &out->method_len);
+  out->payload = req_field(r, 2, &out->payload_len);
+  out->attachment = req_field(r, 3, &out->attachment_len);
+  out->meta = req_field(r, 4, &out->meta_len);
+}
+
+// Batch take that fills a record per request: one condvar round covers a
+// burst, and the py lane reads every field of it after ONE crossing, so
+// it gives up the interpreter lock once per take and not once per field.
+int nat_take_request_batch_info(NatReqInfo* out, int max, int timeout_ms) {
+  if (max <= 0) return 0;
+  NatServer* srv;
+  {
+    std::lock_guard g(g_rt_mu);
+    srv = g_rpc_server;
+    if (srv == nullptr) return 0;
+    // keeps the server alive across the blocking wait
+    NAT_REF_ACQUIRE(srv, srv.taker);
+  }
+  std::vector<PyRequest*> taken((size_t)max);
+  int n = srv->take_py_batch(taken.data(), max, timeout_ms);
+  NAT_REF_RELEASE(srv, srv.taker);
+  for (int i = 0; i < n; i++) nat_req_info(taken[i], &out[i]);
+  return n;
 }
 
 int64_t nat_req_cid(void* h) { return ((PyRequest*)h)->cid; }

@@ -135,6 +135,7 @@ static const char* kCounterNames[NS_COUNTER_COUNT] = {
     "nat_py_queue_wait_ns",
     "nat_sock_write_batches",
     "nat_sock_write_batch_frames",
+    "nat_py_takes",
 };
 
 static const char* kLaneNames[NL_LANE_COUNT] = {

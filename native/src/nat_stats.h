@@ -107,6 +107,9 @@ enum NatCounterId : int {
                             // (charged from Python, once per taken batch)
   NS_SOCK_WRITE_BATCHES,    // nat_sock_write_batch calls
   NS_SOCK_WRITE_BATCH_FRAMES,  // frames handed to nat_sock_write_batch
+  NS_PY_TAKES,               // py-lane takes that returned at least one
+                            // request (nat_py_dispatches / this = requests
+                            // a take hands over)
   NS_COUNTER_COUNT,
 };
 

@@ -80,6 +80,31 @@ def test_ring_wrap_integrity():
         lib.nat_req_free(h)
 
 
+def test_request_record_reads_the_arena_views():
+    """A descriptor-lane request's record (nat_req_info, what the py-lane
+    batch take fills) follows nat_req_field's rules: its payload is the
+    view into the blob arena, and req_tuple gives what the per-field
+    accessors give."""
+    _fresh_lane(1 << 20)
+    assert lib.nat_shm_worker_attach(lib.nat_shm_lane_name()) == 0
+    payload = bytes(range(256)) * 64
+    assert lib.nat_shm_push_tensor(payload, len(payload), 77) == 0
+    h = lib.nat_shm_take_request(2000)
+    assert h
+    try:
+        rec = native.req_info(h)
+        n = ctypes.c_size_t(0)
+        p = lib.nat_req_field(h, 2, ctypes.byref(n))
+        assert rec.handle == h and rec.kind == 8 and rec.aux == 77
+        assert rec.payload == p and rec.payload_len == n.value
+        assert native.req_tuple(rec) == (
+            h, 8, b"", payload, b"", lib.nat_req_sock_id(h),
+            lib.nat_req_cid(h), b"", b"", 0, lib.nat_req_enqueue_ns(h))
+        assert _take_payload(h) == payload
+    finally:
+        lib.nat_req_free(h)
+
+
 def test_arena_exhaustion_backpressure():
     """Pushing without draining must fail cleanly once the blob arena is
     full (the backpressure bound), and succeed again after a drain frees
