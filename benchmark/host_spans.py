@@ -12,16 +12,21 @@ benchmark's `window` span:
   idle_gaps         trace_reduce's ten longest idle gaps on the first
                     chip, each labelled `<benchmark span> > <phase>`: the
                     benchmark span (push, merge_round) that overlaps it
-                    most, then the phase whose own time (its time less
-                    its children's, on its thread) overlaps it most. A gap
-                    that no phase covers keeps trace_reduce's label.
+                    most, then the phase (of this file's SPANS) whose
+                    own time (its time less its children's, on its
+                    thread) overlaps it most. A gap that no phase covers
+                    keeps trace_reduce's label.
 
-The span names are this file's own list: nothing here imports the program,
-and a program that records none of them reads as no spans.
+The names totalled are those of `span_names()`: every span that a
+`program_span` reader of BENCHMARK.json declares in the `SPANS` of its
+file (`metrics/<name>.py`), and this file's `SPANS`, the phases that
+name the gaps. A reader of a new span declares it there and is read
+with no edit here. Nothing here imports the program, and a program
+that records none of the names reads as no spans.
 
-Readers call `of(data)`, which reduces the trace that run.py leaves in
-TRACE_DIR once per run and keeps the result in `data`. Run as a script it
-prints the totals and the labelled gaps of a trace:
+run.py reduces a traced run's trace once, into `data["host_spans"]`,
+which the readers take through `per_push_us` and `per_span_us`. Run as a
+script it prints the totals, counts and labelled gaps of a trace:
 
     python3 benchmark/host_spans.py <dir-or-xplane.pb> [device ids]
 """
@@ -38,9 +43,7 @@ if BENCH_DIR not in sys.path:
 import lib  # noqa: E402
 import trace_reduce  # noqa: E402
 
-# where run.py has the profiler write the window's trace
-TRACE_DIR = os.path.join(lib.ROOT, ".bench_trace")
-
+# the phases that name the idle gaps; totalled too
 SPANS = (
     "rpc.handle", "rpc.respond",
     "tensor.claim", "tensor.stage", "tensor.fill", "tensor.view",
@@ -50,24 +53,42 @@ SPANS = (
 )
 
 
-def _lines(pd):
-    """[[(start_ns, end_ns, name), ...] per host line] of the phase spans."""
+def declared_spans() -> set:
+    """The span names that the `program_span` readers of BENCHMARK.json
+    declare in their `SPANS`."""
+    names = set()
+    for m in lib.benchmark_spec()["per_layer"]:
+        if m["source"] == "program_span":
+            names.update(lib.load_module(
+                os.path.join(BENCH_DIR, "metrics", m["name"] + ".py"),
+                "metric_" + m["name"]).SPANS)
+    return names
+
+
+def span_names() -> set:
+    return declared_spans() | set(SPANS)
+
+
+def _lines(pd, names):
+    """[[(start_ns, end_ns, name), ...] per host line] of the named
+    spans."""
     out = []
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
             evs = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
-                   for ev in line.events if ev.name in SPANS]
+                   for ev in line.events if ev.name in names]
             if evs:
                 out.append(evs)
     return out
 
 
 def reduce(pd) -> dict:
+    """Totals and counts in the window of the spans of `span_names()`."""
     lo, hi = trace_reduce.window_of(pd)
     totals, counts = {}, {}
-    for evs in _lines(pd):
+    for evs in _lines(pd, span_names()):
         for s, e, name in evs:
             if e > lo and s < hi:
                 totals[name] = totals.get(name, 0) + min(e, hi) - max(s, lo)
@@ -76,30 +97,32 @@ def reduce(pd) -> dict:
             "host_span_counts": counts}
 
 
-def of(data: dict):
-    """The reduction of this run's trace, or None for a run without one."""
-    if "host_spans" not in data:
-        data["host_spans"] = (reduce(trace_reduce.load(TRACE_DIR))
-                              if data.get("trace") else None)
-    return data["host_spans"]
-
-
-def acknowledged(data: dict) -> int:
-    return sum(1 for r in data["records"] if r["ok"])
+def _found(data: dict, names):
+    """(seconds, events) of each named span the run's reduction holds."""
+    hs = data.get("host_spans")
+    if not hs:
+        return []
+    return [(hs["host_span_totals"][n], hs["host_span_counts"][n])
+            for n in names if hs["host_span_counts"].get(n)]
 
 
 def per_push_us(data: dict, names) -> float | None:
     """Summed seconds of the named spans in the window per acknowledged
     request, in µs; None when no trace, no request or none of the spans."""
-    hs = of(data)
-    ok = acknowledged(data)
-    if not hs or not ok:
+    ok = sum(1 for r in data["records"] if r["ok"])
+    found = _found(data, names)
+    if not found or not ok:
         return None
-    found = [hs["host_span_totals"][n] for n in names
-             if n in hs["host_span_totals"]]
+    return sum(t for t, _ in found) / ok * 1e6
+
+
+def per_span_us(data: dict, names) -> float | None:
+    """Mean µs of one event of the named spans in the window; None when
+    there is no trace or none of the spans."""
+    found = _found(data, names)
     if not found:
         return None
-    return sum(found) / ok * 1e6
+    return sum(t for t, _ in found) / sum(n for _, n in found) * 1e6
 
 
 def self_segments(events):
@@ -141,7 +164,8 @@ def idle_gaps(pd, device_ids):
             if edges[k + 1] > edges[k]]
     gaps.sort(key=lambda g: g[0] - g[1])
     bench = trace_reduce.host_spans(pd, lo, hi)
-    segments = [seg for evs in _lines(pd) for seg in self_segments(evs)]
+    segments = [seg for evs in _lines(pd, SPANS)
+                for seg in self_segments(evs)]
     out = []
     for g in gaps[:trace_reduce.TOP]:
         label = trace_reduce._label(g, bench)

@@ -111,6 +111,17 @@ def idle_share(data: dict):
     return (1.0 - tr["busy_s"] / tr["window_s"]) * 100.0
 
 
+def counter_deltas(data: dict, key: str):
+    """{counter: after - before} of the counters a configuration read
+    before and after the window into `data[key]`, or None for a run (or
+    a program) without them."""
+    c = data.get(key)
+    if not c or not c["before"] or not c["after"]:
+        return None
+    return {k: c["after"][k] - c["before"][k] for k in c["after"]
+            if k in c["before"]}
+
+
 @contextlib.contextmanager
 def swap(obj, attr: str, value):
     """Sets obj.attr to value for the duration of the block."""

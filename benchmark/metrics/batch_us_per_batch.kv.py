@@ -1,8 +1,10 @@
 """Host time of one table batch: the program's `table.batch` spans (the
 consumer's time for a batch: dedupe, staging, dispatch, wait, copy and
 the answers) in the traced window, over their count, in µs."""
-import table_spans
+import host_spans
+
+SPANS = ("table.batch",)
 
 
 def read(data):
-    return table_spans.per_span_us(data, "table.batch")
+    return host_spans.per_span_us(data, SPANS)

@@ -3,6 +3,8 @@
 the traced window, over the pushes completed."""
 import host_spans
 
+SPANS = ("rpc.handle",)
+
 
 def read(data):
-    return host_spans.per_push_us(data, ("rpc.handle",))
+    return host_spans.per_push_us(data, SPANS)

@@ -4,6 +4,8 @@ acknowledged push: the program's `tensor.land_wait` spans
 pushes completed."""
 import host_spans
 
+SPANS = ("tensor.land_wait",)
+
 
 def read(data):
-    return host_spans.per_push_us(data, ("tensor.land_wait",))
+    return host_spans.per_push_us(data, SPANS)

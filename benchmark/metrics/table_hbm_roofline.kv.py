@@ -5,15 +5,16 @@ time. The table program is the only device work in this cell's window,
 so busy time is its time: a traced run on TPU v5 lite showed only its
 operations (the scatter's while loop with its fusions and
 dynamic-update-slice, the row gather, the copies of its arguments)."""
+import lib
 import table_roofline
-import table_spans
 
 
 def read(data):
     tr = data.get("trace")
-    d = table_spans.counter_deltas(data)
-    if not tr or tr["busy_s"] <= 0 or not d or "table_reads" not in d:
-        return None
+    d = lib.counter_deltas(data, "table_counters")
+    if not tr or tr["busy_s"] <= 0 or not d or "table_reads" not in d \
+            or not d["table_reads"] + d["table_updates"]:
+        return None  # no op in the window: no share to read
     least = table_roofline.least_s(data["device_kind"], d["table_reads"],
                                    d["table_updates"])
     return least / tr["busy_s"] * 100.0

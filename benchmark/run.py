@@ -182,11 +182,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     mod.run(win)
 
     if trace:
+        import host_spans
         import trace_reduce
 
-        win.data["trace"] = (trace_reduce.reduce_dir(
-            TRACE_DIR, [d.id for d in devices])
-            if devices[0].platform == "tpu" else None)
+        win.data["trace"] = win.data["host_spans"] = None
+        if devices[0].platform == "tpu":
+            pd = trace_reduce.load(TRACE_DIR)
+            win.data["trace"] = trace_reduce.reduce(
+                pd, [d.id for d in devices])
+            win.data["host_spans"] = host_spans.reduce(pd)
     table = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in metrics_of(spec, name, table):

@@ -1,6 +1,8 @@
 """Each cell's run, with the harness's look for a chip skipped, at a size
 a test run holds: correct when the program is sound, and not correct
-with the control or any planted fault patched in underneath."""
+with the control or any planted fault patched in underneath. The size is
+the cell's own file, `tests/sizes/<cell>.json`: traffic keys that
+override the cell's traffic file."""
 import contextlib
 import os
 import sys
@@ -15,10 +17,6 @@ import lib  # noqa: E402
 import run  # noqa: E402
 
 SPEC = lib.benchmark_spec()
-SMALL = {
-    "store.push2m": {"sizes_kib": [256], "names": 4},
-    "merge.grads1g": {"buckets": 2, "bucket_mib": 1},
-}
 CASES = []
 for cell in SPEC["workloads"]:
     traffic = lib.load_json(os.path.join(BENCH, "traffic",
@@ -38,11 +36,13 @@ def test_run_is_correct_only_when_sound(cell, fault):
         pytest.skip(f"needs {cell['chips']} devices")
     traffic = lib.load_json(os.path.join(BENCH, "traffic",
                                          cell["name"] + ".json"))
+    small = lib.load_json(os.path.join(BENCH, "tests", "sizes",
+                                       cell["name"] + ".json"))
     patch = (faults.patched(cell, traffic, fault) if fault
              else contextlib.nullcontext())
     with patch:
         result, data = run.run_cell(cell["name"], 2**31 + 17, 1.0, False,
                                     require_tpu=False,
-                                    traffic_override=SMALL[cell["name"]])
+                                    traffic_override=small)
     assert data["records"], "the window completed no request"
     assert result["correct"] is (fault is None), result["checks"]

@@ -13,26 +13,8 @@ import host_spans  # noqa: E402
 import lib  # noqa: E402
 
 SPEC = lib.benchmark_spec()
-LAYER = {m["name"]: m for m in SPEC["per_layer"]}
-
-NEW = {
-    "py_queue_wait_us_per_push.bulk": (
-        "program_counter", "dispatcher and Python service lane",
-        "tensor_GBps", ["store.push2m"]),
-    "handler_us_per_push.bulk": (
-        "program_span", "dispatcher and Python service lane",
-        "tensor_GBps", ["store.push2m"]),
-    "claim_us_per_push.bulk": (
-        "program_span", "sockets and fabric", "tensor_GBps",
-        ["store.push2m"]),
-    "land_dispatch_us_per_push.bulk": (
-        "program_span", "device landing", "tensor_GBps", ["store.push2m"]),
-    "land_wait_us_per_push.bulk": (
-        "program_span", "device landing", "tensor_GBps", ["store.push2m"]),
-    "launch_us_per_allreduce.merge": (
-        "program_span", "collective program", "merge_GBps",
-        ["merge.grads1g"]),
-}
+SPAN_READERS = [m["name"] for m in SPEC["per_layer"]
+                if m["source"] == "program_span"]
 
 
 def reader(name):
@@ -47,19 +29,6 @@ def records(ok, failed=0):
 def spans(**seconds):
     return {"host_span_totals": dict(seconds),
             "host_span_counts": {k: 4 for k in seconds}}
-
-
-@pytest.mark.parametrize("name", sorted(NEW))
-def test_entry(name):
-    m = LAYER[name]
-    assert (m["source"], m["layer"], m["moves"], m["workloads"]) == \
-        NEW[name]
-    assert m["unit"] == "us" and m["better"] == "lower"
-
-
-def test_new_entries_follow_the_accepted_ones():
-    names = [m["name"] for m in SPEC["per_layer"]]
-    assert names[-len(NEW):] == list(NEW)
 
 
 @pytest.mark.parametrize("name,totals,want", [
@@ -79,18 +48,26 @@ def test_push_span_readers(name, totals, want):
     assert reader(name).read(data) == pytest.approx(want)
 
 
-@pytest.mark.parametrize("name", [n for n in NEW if "span" in NEW[n][0]])
+@pytest.mark.parametrize("name", SPAN_READERS)
 def test_span_readers_read_none_without_their_spans(name):
+    r = reader(name)
+    own = {s: 1.0 for s in r.SPANS}
+    others = {s: 1.0 for s in host_spans.span_names() - set(r.SPANS)}
     # an untraced run, a trace of a program without the spans, no pushes
-    assert reader(name).read({"records": records(5), "trace": None}) is None
-    assert reader(name).read({"records": records(5),
-                              "host_spans": spans(**{"py.gc": 1.0})}) \
-        is None
+    assert r.read({"records": records(5), "trace": None}) is None
+    assert r.read({"records": records(5),
+                   "host_spans": spans(**others)}) is None
+    assert r.read({"records": records(5), "host_spans": spans(**own)}) > 0
     if name.endswith(".bulk"):  # per push: none acknowledged
-        assert reader(name).read({"records": [],
-                                  "host_spans": spans(**{
-                                      s: 1.0 for s in host_spans.SPANS})}) \
-            is None
+        assert r.read({"records": [],
+                       "host_spans": spans(**own, **others)}) is None
+
+
+@pytest.mark.parametrize("name", SPAN_READERS)
+def test_span_readers_declare_their_spans(name):
+    names = reader(name).SPANS
+    assert isinstance(names, tuple) and names
+    assert all(isinstance(n, str) and n for n in names)
 
 
 def test_allreduce_launch_reader():
@@ -115,11 +92,3 @@ def test_queue_wait_reader():
     assert r.read({"records": records(4)}) is None
     assert r.read({"records": [], "counters": {"before": before,
                                                  "after": before}}) is None
-
-
-def test_of_reduces_a_run_once_and_reads_none_untraced():
-    data = {"records": [], "trace": None}
-    assert host_spans.of(data) is None and data["host_spans"] is None
-    kept = spans(**{"rpc.handle": 1.0})
-    assert host_spans.of({"host_spans": kept, "trace": {"busy_s": 1}}) \
-        is kept

@@ -106,6 +106,21 @@ def test_cells(cell):
     assert any(reports(cell["name"], m) for m in LAYER.values())
 
 
+@pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda c: c["name"])
+def test_cells_have_a_test_size(cell):
+    # the size tests/test_faults.py runs the cell at on the CPU: traffic
+    # keys of the cell, each overridden with a value of the same type
+    traffic = lib.load_json(os.path.join(BENCH, "traffic",
+                                         cell["traffic"] + ".json"))
+    small = lib.load_json(os.path.join(BENCH, "tests", "sizes",
+                                       cell["name"] + ".json"))
+    assert isinstance(small, dict) and small
+    assert "kind" not in small
+    for key, value in small.items():
+        assert key in traffic, key
+        assert type(value) is type(traffic[key]), key
+
+
 def test_at_most_half_of_the_cells_take_four_chips():
     four = sum(1 for w in SPEC["workloads"] if w["chips"] == 4)
     assert four <= max(1, len(SPEC["workloads"]) // 2)
@@ -133,6 +148,24 @@ def test_metrics(metric):
     for cell in CELLS:
         if reports(cell, metric):
             assert reports(cell, moved), (cell, metric["name"])
+
+
+@pytest.mark.parametrize("metric", SPEC["per_layer"],
+                         ids=lambda m: m["name"])
+def test_reads_none_without_its_input(metric):
+    # an untraced run, with no counters and no acknowledged request
+    reader = lib.load_module(os.path.join(BENCH, "metrics",
+                                          metric["name"] + ".py"), "m")
+    data = {"records": [{"ok": False}] * 3, "trace": None,
+            "host_spans": None}
+    assert reader.read(data) is None
+
+
+@pytest.mark.parametrize("metric", [m for m in SPEC["per_layer"]
+                                    if m["unit"] == "us"],
+                         ids=lambda m: m["name"])
+def test_a_time_is_better_lower(metric):
+    assert metric["better"] == "lower"
 
 
 def test_one_layer_name_per_layer():

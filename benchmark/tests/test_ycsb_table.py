@@ -199,22 +199,19 @@ def test_wrong_fields_counts_fields_off_their_candidates():
 # -- the readers ------------------------------------------------------------
 
 LAYER = {m["name"]: m for m in SPEC["per_layer"]}
-NEW = ("ops_per_batch.kv", "queue_wait_us_per_op.kv", "batch_us_per_batch.kv",
-       "table_wait_us_per_batch.kv", "table_hbm_roofline.kv")
+READERS = ("ops_per_batch.kv", "queue_wait_us_per_op.kv",
+           "batch_us_per_batch.kv", "table_wait_us_per_batch.kv",
+           "table_hbm_roofline.kv")
 
 
 def test_entries_of_the_cell():
-    names = [m["name"] for m in SPEC["per_layer"]]
-    at = [names.index(n) for n in NEW]
-    # appended in order after the entries the benchmark had before them
-    assert at == sorted(at) and at[0] > names.index(
-        "launch_us_per_allreduce.merge")
-    for name in NEW:
-        assert LAYER[name]["workloads"] == ["kv.ycsb_b"]
+    # the readers tested below report in this cell; other cells may share
+    # them
+    for name in READERS:
+        assert "kv.ycsb_b" in LAYER[name]["workloads"]
         assert LAYER[name]["moves"] == "tensor_GBps"
     e2e = {m["name"]: m for m in SPEC["end_to_end"]}
-    assert e2e["tensor_GBps"]["workloads"][:2] == ["store.push2m",
-                                                   "kv.ycsb_b"]
+    assert "kv.ycsb_b" in e2e["tensor_GBps"]["workloads"]
     cell = {c["name"]: c for c in SPEC["workloads"]}["kv.ycsb_b"]
     assert (cell["config"], cell["chips"]) == ("ycsb_table", 1)
 
@@ -238,16 +235,16 @@ def test_counter_readers():
 
 
 def test_span_readers():
-    data = {"table_spans": {
-        "totals": {"table.batch": 0.5, "table.wait": 0.3},
-        "counts": {"table.batch": 1000, "table.wait": 1000}}}
+    data = {"records": [], "host_spans": {
+        "host_span_totals": {"table.batch": 0.5, "table.wait": 0.3},
+        "host_span_counts": {"table.batch": 1000, "table.wait": 1000}}}
     assert reader("batch_us_per_batch.kv").read(data) == pytest.approx(500)
     assert reader("table_wait_us_per_batch.kv").read(data) == \
         pytest.approx(300)
     for name in ("batch_us_per_batch.kv", "table_wait_us_per_batch.kv"):
         assert reader(name).read({"trace": None}) is None
-        assert reader(name).read({"table_spans": {
-            "totals": {}, "counts": {}}}) is None
+        assert reader(name).read({"host_spans": {
+            "host_span_totals": {}, "host_span_counts": {}}}) is None
 
 
 def test_roofline_reader():

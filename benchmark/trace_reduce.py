@@ -172,10 +172,6 @@ def reduce(pd, device_ids):
     }
 
 
-def reduce_dir(path: str, device_ids):
-    return reduce(load(path), device_ids)
-
-
 def describe(pd, per_line: int = 6):
     for plane in pd.planes:
         print(f"PLANE {plane.name}")
